@@ -80,7 +80,7 @@ from repro.core.dwarfs.base import fit_buffer
 from repro.core.proxy import ProxyBenchmark
 from repro.core.structsearch import (StructuralTuner, ai_fidelity_harness,
                                      structural_fidelity_harness)
-from repro.core.workloads import PROXY_SPECS
+from repro.core.workloads import PROXY_SPECS, mega_chain
 
 from .common import ROOT, csv_row
 from .lm_proxy import lm_proxy_summary
@@ -449,15 +449,7 @@ def bench_megakernel_sweep() -> Dict[str, object]:
     interpreter overhead, not accelerator wins (see ROADMAP)."""
     from repro.kernels.dispatch import megakernel_enabled, resolve_backend
 
-    P = lambda w, **e: ComponentParams(data_size=2048, chunk_size=128,
-                                       weight=w, extra=e)
-    dag = ProxyDAG(
-        "bench_mega", {"src": 2048},
-        [Edge("quick_sort", ["src"], "a", P(2)),
-         Edge("hash", ["a"], "b", P(3, rounds=2)),
-         Edge("top_k", ["b"], "c", P(2, k=8)),
-         Edge("min_max", ["c"], "out", P(1))],
-        "out")
+    dag = mega_chain()
     fused = schedule.lower(dag, threshold=1e30, cache=False)
     unfused = schedule.lower(dag, threshold=0.0, cache=False)
     engaged_expected = (fused.mega_stage_count > 0
@@ -1107,6 +1099,8 @@ def _csv_rows(run_path, sweep, tune, population, plan_sweep, mega,
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     for row in bench_compile_vs_run():
         print(row)
     print(f"wrote {BENCH_JSON}")

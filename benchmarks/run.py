@@ -12,6 +12,8 @@ import traceback
 
 
 def main() -> None:
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from . import paper_tables as pt
     from .compile_vs_run import bench_compile_vs_run
     from .lm_proxy import bench_lm_proxy

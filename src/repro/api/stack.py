@@ -19,8 +19,10 @@ JAX-native execution models:
   * ``openmp``  — single-process jit; XLA intra-op threading = OpenMP threads.
   * ``mpi``     — explicit SPMD via shard_map over a device mesh with the
                   collectives spelled out (the MPI execution model).
-  * ``spark``   — global-view jit with input sharding constraints;
+  * ``spark``   — inputs placed on the mesh with sharding constraints;
                   intermediates stay device-resident ("in-memory RDD").
+                  Plan programs run per device under shard_map, as
+                  ``mpi``'s do: XLA cannot partition a Pallas kernel.
   * ``hadoop``  — staged execution: every intermediate DAG node is
                   materialized through *host* memory ("HDFS spill"), which is
                   the disk-I/O behaviour the paper measures for Hadoop jobs.
@@ -37,11 +39,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-try:  # jax>=0.4.30 experimental location; stubbed out if unavailable
-    from jax.experimental.shard_map import shard_map as _shard_map
-except ImportError:  # pragma: no cover - depends on container jax build
-    _shard_map = None
 
 from ..core import schedule as plans
 from ..core.cachetools import hit_rate
@@ -417,8 +414,9 @@ class Stack(abc.ABC):
         weighted cost run one vmapped call per bucket, so each bucket's
         batched ``while`` trips only to its own maximum instead of the
         population-wide straggler — recovering the sequential-sum cost
-        model while keeping per-lane results bit-identical (vmap lanes
-        are batch-composition independent).  Population plans lower
+        model while keeping per-lane results bit-identical to single runs
+        (on a TPU, matrix dwarfs inside a vmap of several lanes are the
+        exception: they round differently).  Population plans lower
         unfused (``plans.lower_population``): per-edge loops give the
         schedule its per-edge trip bounds, and a fused switch under a
         batched candidate axis would execute every branch per trip."""
@@ -602,18 +600,22 @@ class OpenMPStack(Stack):
         return out, 0.0
 
 
-class MPIStack(Stack):
-    """Explicit SPMD over a device mesh with collectives spelled out.
+class _MeshStack(Stack):
+    """A stack whose plan programs run on every device of a 1-D mesh.
 
-    Single runs are replicated across ranks and combined with an
-    all-reduce mean (identical per-rank inputs keep results bit-stable
-    across any rank count); batched runs shard the rng batch over ranks.
-    """
+    Each program runs per device under ``shard_map``: a Pallas kernel
+    cannot be partitioned by XLA, so a program that may hold one is never
+    handed to the partitioner.  Batch axes — ``run_batch`` rngs,
+    population buckets, serving chunks — shard over the mesh axis when
+    their size divides it, each device vmapping its own slice; otherwise
+    (and for single runs) every device computes the whole program."""
 
-    name = "mpi"
+    #: the mesh axis name when none is given
+    axis_name = "rank"
 
-    def __init__(self, mesh: Optional[Mesh] = None, axis: str = "rank"):
-        self.axis = axis
+    def __init__(self, mesh: Optional[Mesh] = None,
+                 axis: Optional[str] = None):
+        self.axis = axis or self.axis_name
         self._mesh = mesh          # built lazily: importing repro.api must
                                    # not initialize the JAX backend
 
@@ -623,20 +625,83 @@ class MPIStack(Stack):
             self._mesh = _default_mesh(self.axis)
         return self._mesh
 
+    def _lanes(self, n: int) -> P:
+        """Spec of an ``n``-long batch axis: sharded when ``n`` divides
+        the mesh axis, else replicated."""
+        from ..distributed.sharding import candidate_spec_axis
+        ax = candidate_spec_axis(self.mesh, n, prefer=(self.axis,))
+        return P() if ax is None else P(ax)
+
+    def _per_device(self, fn: Callable, in_specs, out_specs) -> Callable:
+        return jax.shard_map(fn, mesh=self.mesh, in_specs=in_specs,
+                             out_specs=out_specs, check_vma=False)
+
+    def _single(self, out):
+        """Combine a single run's per-device results (identity here)."""
+        return out
+
+    def _wrap_parametric(self, pfn, batch):
+        if batch:
+            def f(rngs, dyn):
+                CACHE_STATS["traces"] += 1
+                lanes = self._lanes(rngs.shape[0])
+                return self._per_device(
+                    lambda rs, d: jax.vmap(lambda r: pfn(r, d))(rs),
+                    (lanes, P()), lanes)(rngs, dyn)
+        else:
+            def f(rng, dyn):
+                CACHE_STATS["traces"] += 1
+                return self._per_device(
+                    lambda r, d: self._single(pfn(r, d)),
+                    (P(), P()), P())(rng, dyn)
+        return jax.jit(f, donate_argnums=_donate_argnums())
+
+    def _wrap_population(self, plan, n):
+        """Shard each bucket's candidate axis over the mesh: every device
+        vmaps its own slice of the bucket (SPMD tuner sweep)."""
+        pop, lanes = plan.build_population(), self._lanes(n)
+
+        def f(rng, dynb):
+            CACHE_STATS["traces"] += 1
+            return self._per_device(pop, (P(), lanes), lanes)(rng, dynb)
+
+        return jax.jit(f)
+
+    def _wrap_serve(self, plan, n):
+        """Shard the serving micro-batch over the mesh: request rngs and
+        dyn params shard together on the request axis, each device
+        vmapping its own slice of the chunk."""
+        pfn, lanes = plan.build_parametric(), self._lanes(n)
+
+        def f(rngs, dynb):
+            CACHE_STATS["traces"] += 1
+            return self._per_device(jax.vmap(pfn), (lanes, lanes),
+                                    lanes)(rngs, dynb)
+
+        return jax.jit(f)
+
+
+class MPIStack(_MeshStack):
+    """Explicit SPMD over a device mesh with collectives spelled out.
+
+    Single runs are replicated across ranks and combined with an
+    all-reduce mean (identical per-rank inputs keep results bit-stable
+    across any rank count); batched runs shard the rng batch over ranks.
+    """
+
+    name = "mpi"
+
     def _pmean_floats(self, out):
         return jax.tree_util.tree_map(
             lambda x: (jax.lax.pmean(x, self.axis)
                        if jnp.issubdtype(jnp.asarray(x).dtype, jnp.floating)
                        else x), out)
 
+    _single = _pmean_floats
+
     def _execute(self, fn, args):
-        if _shard_map is None:  # pragma: no cover - jax without shard_map
-            out = jax.jit(fn)(*args)
-            jax.block_until_ready(out)
-            return out, 0.0
-        spmd = _shard_map(lambda *a: self._pmean_floats(fn(*a)),
-                          mesh=self.mesh, in_specs=P(), out_specs=P(),
-                          check_rep=False)
+        spmd = self._per_device(lambda *a: self._pmean_floats(fn(*a)),
+                                P(), P())
         out = jax.jit(spmd)(*args)
         jax.block_until_ready(out)
         return out, 0.0
@@ -644,93 +709,21 @@ class MPIStack(Stack):
     def _execute_batch(self, fn, rngs):
         n = self.mesh.devices.size
         batch = int(rngs.shape[0])
-        if _shard_map is None or batch % n != 0:  # pragma: no cover
+        if batch % n != 0:  # pragma: no cover
             return self._execute(jax.vmap(fn), (rngs,))
-        spmd = _shard_map(jax.vmap(fn), mesh=self.mesh,
-                          in_specs=P(self.axis), out_specs=P(self.axis),
-                          check_rep=False)
+        spmd = self._per_device(jax.vmap(fn), P(self.axis), P(self.axis))
         out = jax.jit(spmd)(rngs)
         jax.block_until_ready(out)
         return out, 0.0
 
-    def _wrap_parametric(self, pfn, batch):
-        if _shard_map is None:  # pragma: no cover - jax without shard_map
-            return super()._wrap_parametric(pfn, batch)
-        n = self.mesh.devices.size
-        if batch:
-            def f(rngs, dyn):
-                CACHE_STATS["traces"] += 1
-                vm = lambda rs, d: jax.vmap(lambda r: pfn(r, d))(rs)
-                if rngs.shape[0] % n != 0:  # pragma: no cover
-                    return vm(rngs, dyn)
-                return _shard_map(vm, mesh=self.mesh,
-                                  in_specs=(P(self.axis), P()),
-                                  out_specs=P(self.axis),
-                                  check_rep=False)(rngs, dyn)
-        else:
-            def f(rng, dyn):
-                CACHE_STATS["traces"] += 1
-                spmd = _shard_map(
-                    lambda r, d: self._pmean_floats(pfn(r, d)),
-                    mesh=self.mesh, in_specs=(P(), P()), out_specs=P(),
-                    check_rep=False)
-                return spmd(rng, dyn)
-        return jax.jit(f, donate_argnums=_donate_argnums())
 
-    def _wrap_population(self, plan, n):
-        """Shard each bucket's candidate axis over the ranks: every rank
-        vmaps its own slice of the bucket (SPMD tuner sweep — ROADMAP's
-        multi-device dynamic-param batch, now at bucket granularity)."""
-        from ..distributed.sharding import candidate_spec_axis
-        if _shard_map is None or candidate_spec_axis(
-                self.mesh, n, prefer=(self.axis,)) is None:
-            return super()._wrap_population(plan, n)  # pragma: no cover
-        pop = plan.build_population()
-
-        def f(rng, dynb):
-            CACHE_STATS["traces"] += 1
-            return _shard_map(pop, mesh=self.mesh,
-                              in_specs=(P(), P(self.axis)),
-                              out_specs=P(self.axis),
-                              check_rep=False)(rng, dynb)
-
-        return jax.jit(f)
-
-    def _wrap_serve(self, plan, n):
-        """Shard the serving micro-batch over the ranks: request rngs and
-        dyn params shard together on the request axis, each rank vmapping
-        its own slice of the chunk."""
-        from ..distributed.sharding import candidate_spec_axis
-        if _shard_map is None or candidate_spec_axis(
-                self.mesh, n, prefer=(self.axis,)) is None:
-            return super()._wrap_serve(plan, n)  # pragma: no cover
-        pfn = plan.build_parametric()
-
-        def f(rngs, dynb):
-            CACHE_STATS["traces"] += 1
-            return _shard_map(jax.vmap(pfn), mesh=self.mesh,
-                              in_specs=(P(self.axis), P(self.axis)),
-                              out_specs=P(self.axis),
-                              check_rep=False)(rngs, dynb)
-
-        return jax.jit(f)
-
-
-class SparkStack(Stack):
-    """Global-view jit with input sharding constraints; intermediates stay
-    device-resident (the "in-memory RDD" model)."""
+class SparkStack(_MeshStack):
+    """Global-view placement: inputs are put on the mesh with sharding
+    constraints and intermediates stay device-resident (the "in-memory
+    RDD" model); the plan programs run per device (:class:`_MeshStack`)."""
 
     name = "spark"
-
-    def __init__(self, mesh: Optional[Mesh] = None, axis: str = "worker"):
-        self.axis = axis
-        self._mesh = mesh          # lazy for the same reason as MPIStack
-
-    @property
-    def mesh(self) -> Mesh:
-        if self._mesh is None:
-            self._mesh = _default_mesh(self.axis)
-        return self._mesh
+    axis_name = "worker"
 
     def _spec_for(self, a: Any) -> P:
         shape = getattr(a, "shape", ())

@@ -193,6 +193,33 @@ def _terminals(edges: List[Edge]) -> List[str]:
     return sorted(produced - consumed) or sorted(produced)
 
 
+def _checksum(v: jnp.ndarray) -> jnp.ndarray:
+    """Sum of every element, in a fixed pairwise order.
+
+    On a TPU ``jnp.sum``'s float accumulation order follows the array's
+    layout, so one lane reduced alone and the same lane inside a vmap of
+    several give other bits.  Adding zero-padded halves elementwise fixes
+    the order, so a population or serving lane equals its single run."""
+    v = jnp.ravel(v)
+    if not jnp.issubdtype(v.dtype, jnp.floating):
+        return jnp.sum(v)                  # integer sums are exact
+    n = v.shape[0]
+    v = jnp.pad(v, (0, (1 << max(n - 1, 0).bit_length()) - n))
+    while v.shape[0] > 1:
+        half = v.shape[0] // 2
+        v = v[:half] + v[half:]
+    return v[0]
+
+
+def _output(nodes: Dict[str, jnp.ndarray], sink: Optional[str],
+            edges: List[Edge]) -> jnp.ndarray:
+    """The DAG's scalar result: the sink's checksum, or the sum of every
+    terminal node's checksum when there is no sink."""
+    if sink is not None:
+        return _checksum(nodes[sink])
+    return sum(_checksum(nodes[t]) for t in _terminals(edges))
+
+
 @dataclasses.dataclass
 class ProxyDAG:
     """Executable DAG of weighted dwarf components."""

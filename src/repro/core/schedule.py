@@ -62,7 +62,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from .dag import (Edge, ProxyDAG, _accumulate, _edge_out, _gather_inputs,
-                  _init_sources, _terminals)
+                  _init_sources, _output)
 from .pool import get_pool
 from .dwarfs import get_component
 from .dwarfs.base import fit_buffer
@@ -135,16 +135,13 @@ def resolve_bucket_size(n: int) -> int:
 
 def _edge_body_cost(e: Edge) -> float:
     """Scalar per-repeat cost of one edge body (flops + vpu ops + bytes),
-    from the engine's cached compositional report; falls back to a
-    bytes-proportional estimate if HLO analysis is unavailable."""
-    try:
-        from .engine import _body_report
-        rep = _body_report(e)
-        cost = float(rep.flops + rep.vpu_ops + rep.bytes_accessed)
-        if cost > 0.0:
-            return cost
-    except Exception:  # pragma: no cover - analysis backend unavailable
-        pass
+    from the engine's cached compositional report; a body the analysis
+    prices at zero falls back to a bytes-proportional estimate."""
+    from .engine import _body_report
+    rep = _body_report(e)
+    cost = float(rep.flops + rep.vpu_ops + rep.bytes_accessed)
+    if cost > 0.0:
+        return cost
     return float(8 * e.params.rounded().data_size)
 
 
@@ -197,16 +194,18 @@ class FusedStage:
 def _mega_eligible(group: Sequence[Edge]) -> bool:
     """May this fused group lower to the one-kernel megakernel?  Every
     member must be ``pallas_capable`` *and* have a registered segment
-    body under its params, and the shared carry must fit the VMEM
-    budget.  Pure structure — no env/backend reads — so the flag caches
-    with the plan."""
-    from ..kernels.megakernel import CARRY_VMEM_BYTES, mega_capable
+    body under its params, the members must share one carry layout
+    (:func:`~repro.kernels.megakernel.mega_lane`), and the carry must fit
+    the VMEM budget.  Pure structure — no env/backend reads — so the flag
+    caches with the plan."""
+    from ..kernels.megakernel import CARRY_VMEM_BYTES, mega_capable, mega_lane
     if len(group) < 2:
         return False
     if 4 * group[-1].params.rounded().data_size > CARRY_VMEM_BYTES:
         return False
-    return all(get_component(e.component).pallas_capable
-               and mega_capable(e.component, e.params) for e in group)
+    return (mega_lane([(e.component, e.params) for e in group]) is not None
+            and all(get_component(e.component).pallas_capable
+                    and mega_capable(e.component, e.params) for e in group))
 
 
 def _partition(dag: ProxyDAG, edges: Sequence[Edge],
@@ -342,7 +341,7 @@ def _mega_out(members: Sequence[Tuple[int, Edge]], x: jnp.ndarray,
     executable cache key carries the backend override and the megakernel
     flag, so demoted and promoted traces never share an executable."""
     from ..kernels.dispatch import default_interpret, megakernel_enabled
-    from ..kernels.megakernel import mega_body, mega_stage_kernel
+    from ..kernels.megakernel import mega_body, mega_lane, mega_stage_kernel
     if not megakernel_enabled():
         return None
     ws, bodies = [], []
@@ -363,7 +362,9 @@ def _mega_out(members: Sequence[Tuple[int, Edge]], x: jnp.ndarray,
     if x0.dtype != jnp.float32:
         return None
     weights = jnp.stack([jnp.asarray(w, jnp.int32) for w in ws])
-    out = mega_stage_kernel(x0, weights, bodies,
+    # eligibility (_mega_eligible) guarantees the members share a layout
+    lane = mega_lane([(e.component, e.params) for _, e in members])
+    out = mega_stage_kernel(x0, weights, bodies, lane,
                             interpret=default_interpret())
     # The kernel's buffer is bit-identical to the switch path, but XLA may
     # fuse a downstream reduce *into* the interpret-mode lowering with a
@@ -552,9 +553,7 @@ class ExecutionPlan:
                 xs = [nodes[s] for s in stage.src]
                 nodes[stage.dst] = fn(rng, xs, nodes.get(stage.dst),
                                       self._stage_dyn(stage, dyn))
-            if sink is not None:
-                return jnp.sum(nodes[sink])
-            return sum(jnp.sum(nodes[t]) for t in _terminals(edges))
+            return _output(nodes, sink, edges)
 
         return execute
 
@@ -598,9 +597,7 @@ class ExecutionPlan:
                   for s in self.stages]
 
         def finalize_fn(nodes: Dict[str, jnp.ndarray]) -> jnp.ndarray:
-            if sink is not None:
-                return jnp.sum(nodes[sink])
-            return sum(jnp.sum(nodes[t]) for t in _terminals(edges))
+            return _output(nodes, sink, edges)
 
         return init_fn, stages, finalize_fn
 

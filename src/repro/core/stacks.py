@@ -41,12 +41,8 @@ def mpi(fn: Callable, mesh: jax.sharding.Mesh, axis: str, *args,
     ``axis`` and caller-controlled ``out_specs``.  (``MPIStack.run`` instead
     replicates inputs and all-reduces outputs — a different contract.)"""
     _warn("mpi")
-    from ..api.stack import _shard_map
-    if _shard_map is None:  # pragma: no cover - jax without shard_map
-        raise NotImplementedError("legacy mpi() needs jax shard_map; use "
-                                  "repro.api.MPIStack instead")
-    sharded = _shard_map(fn, mesh=mesh, in_specs=P(axis),
-                         out_specs=out_specs, check_rep=False)
+    sharded = jax.shard_map(fn, mesh=mesh, in_specs=P(axis),
+                            out_specs=out_specs, check_vma=False)
     out = jax.jit(sharded)(*args)
     jax.block_until_ready(out)
     return out, 0.0

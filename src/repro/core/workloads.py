@@ -20,6 +20,8 @@ import jax.numpy as jnp
 
 from ..api.spec import SPEC_VERSION, ProxySpec
 from ..data import generators as gen
+from .dag import Edge, ProxyDAG
+from .dwarfs import ComponentParams
 from .proxy import ProxyBenchmark
 
 # ---------------------------------------------------------------------------
@@ -406,6 +408,22 @@ PROXY_SPECS: Dict[str, Dict[str, Any]] = {
     "lm_train": LM_TRAIN_PROXY_SPEC,
     "lm_decode": LM_DECODE_PROXY_SPEC,
 }
+
+
+def mega_chain(size: int = 2048, chunk: int = 128) -> ProxyDAG:
+    """A private linear chain whose members all have registered megakernel
+    segment bodies (sort, hash, top-k, min/max): it fuses into one
+    mega-eligible stage while its carry fits VMEM."""
+    p = lambda w, **e: ComponentParams(data_size=size, chunk_size=chunk,
+                                       weight=w, extra=e)
+    return ProxyDAG(
+        "mega_chain", {"src": size},
+        [Edge("quick_sort", ["src"], "a", p(2)),
+         Edge("hash", ["a"], "b", p(3, rounds=2)),
+         Edge("top_k", ["b"], "c", p(2, k=8)),
+         Edge("min_max", ["c"], "out", p(1))],
+        "out")
+
 
 def seed_structures(names: Optional[Sequence[str]] = None) -> List["ProxyDAG"]:
     """Seed pool for the structural search: every named Table-3 proxy's

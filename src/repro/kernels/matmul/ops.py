@@ -15,13 +15,25 @@ from ..dispatch import default_interpret, resolve_backend
 from .kernel import matmul_kernel
 
 
+def _tile(block: int, dim: int, align: int) -> int:
+    """A block edge the TPU lowering accepts: the whole dimension when the
+    requested block covers it, else the block rounded up to a multiple of
+    ``align`` (8 sublanes for rows, 128 lanes for columns)."""
+    if dim <= block:
+        return dim
+    t = -(-block // align) * align
+    return dim if t >= dim else t
+
+
 @functools.partial(jax.jit, static_argnames=("block_m", "block_n", "block_k",
                                              "interpret"))
 def _matmul_pallas(a: jnp.ndarray, b: jnp.ndarray, *, block_m: int,
                    block_n: int, block_k: int, interpret: bool) -> jnp.ndarray:
     M, K = a.shape
     _, N = b.shape
-    bm, bn, bk = (min(block_m, M), min(block_n, N), min(block_k, K))
+    # K is a's lane dim and b's sublane dim, N is a lane dim
+    bm, bn, bk = (_tile(block_m, M, 8), _tile(block_n, N, 128),
+                  _tile(block_k, K, 128))
     pm, pn, pk = (-M) % bm, (-N) % bn, (-K) % bk
     ap = jnp.pad(a, ((0, pm), (0, pk))) if (pm or pk) else a
     bp = jnp.pad(b, ((0, pk), (0, pn))) if (pk or pn) else b

@@ -1,2 +1,2 @@
-from .bodies import mega_body, mega_capable
+from .bodies import mega_body, mega_capable, mega_lane
 from .kernel import CARRY_VMEM_BYTES, mega_stage_kernel

@@ -1,7 +1,8 @@
 """Registered in-kernel segment bodies for the FusedStage megakernel.
 
-A *body* is a pure ``flat_f32 -> flat_f32`` function over the stage's
-carry buffer replicating exactly one repeat of a dwarf component — the
+A *body* is a pure ``(rows, lane) f32 -> (rows, lane) f32`` function over
+the stage's carry (laid out by :func:`mega_lane`) replicating exactly one
+repeat of a dwarf component — the
 same value :func:`repro.core.dag._edge_out`'s loop body produces — as
 plain jnp ops traceable *inside* a Pallas kernel (no nested
 ``pallas_call``, no rng).  Only components whose ``apply`` ignores the
@@ -12,18 +13,24 @@ to the ``fori_loop`` + ``lax.switch`` path.
 :func:`mega_body` returns ``None`` when a component has no registered
 body **or** its params break the identity contract (a chunk-row body
 whose ``parallelism`` lane split in ``DwarfComponent.__call__`` would
-re-tile lanes, a non-divisible chunk, a dynamic kernel-static extra) —
-``core/schedule.py`` then keeps the stage on the switch path.
+re-tile lanes, a chunk that is not a whole number of 128-lane vector rows,
+a dynamic kernel-static extra) — ``core/schedule.py`` then keeps the stage
+on the switch path.  Bodies use only elementwise ops, lane rotations and
+row reductions on the 2-D tile: nothing the TPU compiler must reshape.
 """
 
 from __future__ import annotations
 
-from typing import Callable, Optional
+from typing import Callable, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 
 MegaBody = Callable[[jnp.ndarray], jnp.ndarray]
+
+#: the TPU vector register's lane count: chunk-row bodies need whole
+#: vector rows, so a chunk must be a multiple of it
+LANES = 128
 
 #: sentinel the top-k max-sweep masks claimed maxima with (must match
 #: repro.kernels.topk.kernel.NEG_INF for bit-identity with the kernel)
@@ -55,12 +62,14 @@ def _body_hash(p) -> Optional[MegaBody]:
     if rounds is None:
         return None
 
-    def body(flat: jnp.ndarray) -> jnp.ndarray:
-        from ...core.dwarfs.base import _mix32_round, as_u32, u32_to_f32
-        u = as_u32(flat)
+    def body(x: jnp.ndarray) -> jnp.ndarray:
+        from ...core.dwarfs.base import _mix32_round, as_u32
+        u = as_u32(x)
         for _ in range(rounds):
             u = _mix32_round(u)
-        return u32_to_f32(u)
+        # u32_to_f32, with the 24-bit value cast through i32: the TPU
+        # compiler has no u32 -> f32 conversion, and both are exact here
+        return (u >> 8).astype(jnp.int32).astype(jnp.float32) * (1.0 / (1 << 24))
 
     return body
 
@@ -72,52 +81,40 @@ def _body_top_k(p) -> Optional[MegaBody]:
         return None
     k = min(k, c)
 
-    def body(flat: jnp.ndarray) -> jnp.ndarray:
-        x = flat.reshape(-1, c)
-        rows = x.shape[0]
-        cols = jax.lax.broadcasted_iota(jnp.int32, (rows, c), 1)
-        vals = []
-        for _ in range(k):        # the topk kernel's (max, mask) sweep
-            m = x.max(axis=1)
-            first = jnp.min(jnp.where(x == m[:, None], cols, c), axis=1)
-            vals.append(m)
-            x = jnp.where(cols == first[:, None], _NEG_INF, x)
-        v = jnp.stack(vals, axis=1)
-        reps = -(-c // k)
-        return jnp.tile(v, (1, reps))[:, :c].reshape(-1)
+    def body(x: jnp.ndarray) -> jnp.ndarray:
+        cols = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+        slot = cols % k            # column j repeats the (j % k)-th max
+        out = jnp.zeros_like(x)
+        for t in range(k):        # the topk kernel's (max, mask) sweep
+            m = x.max(axis=1, keepdims=True)
+            first = jnp.min(jnp.where(x == m, cols, c), axis=1,
+                            keepdims=True)
+            out = jnp.where(slot == t, m, out)
+            x = jnp.where(cols == first, _NEG_INF, x)
+        return out
 
     return body
 
 
 def _body_full_sort(p) -> Optional[MegaBody]:
     # quick_sort sorts every chunk row; merge_sort merges two sorted
-    # halves, which equals the full row sort whenever the chunk is even
-    # (rounded chunks are multiples of 8, so always here)
+    # halves, which equals the full row sort whenever the chunk is even.
+    # The network runs on the chunk row in place, so the chunk must be a
+    # power of two (no pad columns to concatenate inside the kernel)
+    from ..sort_net.kernel import bitonic_sort_rows
     c = p.chunk_size
-    if c % 2:
+    if c & (c - 1):
         return None
-
-    def body(flat: jnp.ndarray) -> jnp.ndarray:
-        from ..sort_net.kernel import bitonic_sort_rows, next_pow2
-        rows = flat.reshape(-1, c)
-        pn = next_pow2(c) - c
-        if pn:
-            rows = jnp.concatenate(
-                [rows, jnp.full((rows.shape[0], pn), jnp.inf, rows.dtype)],
-                axis=1)
-        return bitonic_sort_rows(rows)[:, :c].reshape(-1)
-
-    return body
+    return bitonic_sort_rows
 
 
 def _body_min_max(p) -> Optional[MegaBody]:
     c = p.chunk_size
 
-    def body(flat: jnp.ndarray) -> jnp.ndarray:
-        rows = flat.reshape(-1, c)
-        mn = rows.min(axis=1, keepdims=True)
-        mx = rows.max(axis=1, keepdims=True)
-        return ((rows - mn) / jnp.maximum(mx - mn, 1e-6)).reshape(-1)
+    def body(x: jnp.ndarray) -> jnp.ndarray:
+        mn = x.min(axis=1, keepdims=True)
+        mx = x.max(axis=1, keepdims=True)
+        return (x - mn) / jnp.maximum(mx - mn, 1e-6)
 
     return body
 
@@ -143,10 +140,26 @@ def mega_body(component: str, p) -> Optional[MegaBody]:
     p = p.rounded()
     if p.data_size % p.chunk_size:          # rounded() guarantees this,
         return None                         # but never trust a caller
-    if component in _CHUNK_ROW and not _lane_split_clean(p):
+    if component in _CHUNK_ROW and (p.chunk_size % LANES
+                                    or not _lane_split_clean(p)):
         return None
     return factory(p)
 
 
 def mega_capable(component: str, p) -> bool:
     return mega_body(component, p) is not None
+
+
+def mega_lane(members: Sequence[Tuple[str, object]]) -> Optional[int]:
+    """Row width of a stage carry's ``(rows, lane)`` VMEM layout, or
+    ``None`` when no single layout serves every member: the chunk of the
+    chunk-row members (they must agree — each body's row is its
+    component's chunk), else one vector register row.  ``members`` are
+    ``(component, params)`` pairs in stage order."""
+    chunks = {p.rounded().chunk_size for comp, p in members
+              if comp in _CHUNK_ROW}
+    if len(chunks) > 1:
+        return None
+    lane = chunks.pop() if chunks else LANES
+    size = members[-1][1].rounded().data_size
+    return lane if size % lane == 0 else None

@@ -4,23 +4,19 @@ The ``fori_loop`` + ``lax.switch`` fused-stage form re-materializes the
 carry through XLA between every trip.  This emitter compiles the whole
 member chain into **one** ``pl.pallas_call``:
 
-* the **grid iterates the segments** of the stage's concatenated trip
-  space — one grid step per member edge, in member order (TPU grid
-  execution is sequential, which is what makes the scratch carry below
-  sound);
-* the **carry stays resident in VMEM scratch** across members: step 0
-  copies the input block in, every step reads/writes the scratch, the
-  last value is written to the output block — no per-member HBM
+* the **carry stays resident in VMEM** for the whole stage: the output
+  block *is* the carry — it is seeded from the input block once and every
+  member's repeats read and write it in place, with no per-member HBM
   round-trip;
-* the **per-segment operand row** (the member's trip count) is a blocked
-  input whose index map follows the segment index, so the Pallas
-  pipeline keeps the *next* segment's operand load in flight while the
-  current segment computes — the standard grid-pipelined double
-  buffering (guide §17) with zero manual semaphores;
-* each segment runs its member's registered body (see
-  :mod:`.bodies`) ``weight`` times via an in-kernel ``fori_loop`` whose
-  bound is read from the operand row — weights are *data*, so stepping a
-  weight never retraces, exactly like the switch path.
+* the carry keeps the stage's 2-D ``(rows, lane)`` layout
+  (:func:`~.bodies.mega_lane`): a chunk-row body sees one chunk per row,
+  exactly the ``(rows, chunk)`` view its component takes, so no body
+  reshapes inside the kernel;
+* the **per-member trip counts** live in SMEM as one ``(k,)`` i32 vector,
+  and each member runs its registered body (see :mod:`.bodies`) that many
+  times via an in-kernel ``fori_loop`` whose bound is read from SMEM —
+  weights are *data*, so stepping a weight never retraces, exactly like
+  the switch path.
 
 Trip order is therefore member 0's repeats, then member 1's, … — the
 same order ``_fused_out``'s segmented trip space executes — and every
@@ -39,56 +35,41 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-#: cap on the resident carry (f32 bytes) — a stage whose buffer cannot
-#: stay VMEM-resident next to the operand pipeline keeps the switch path
-CARRY_VMEM_BYTES = 4 << 20
+#: cap on the resident carry (f32 bytes): the carry's input and output
+#: and the bodies' carry-sized temporaries must fit v5e's default scoped
+#: VMEM (a 4 MiB carry runs out of it); a larger stage keeps the switch path
+CARRY_VMEM_BYTES = 1 << 20
 
 
-def _mega_kernel(w_ref, x_ref, o_ref, acc_ref, *, bodies, rows, lane):
-    seg = pl.program_id(0)
+def _mega_kernel(w_ref, x_ref, o_ref, *, bodies):
+    o_ref[...] = x_ref[...]
+    for m, body in enumerate(bodies):
+        def repeat(_, c, body=body):
+            o_ref[...] = body(o_ref[...])
+            return c
 
-    @pl.when(seg == 0)
-    def _():
-        acc_ref[...] = x_ref[...]
-
-    carry = acc_ref[...]
-    w = w_ref[0, 0]          # this segment's trip count (pipelined load)
-
-    def branch(body):
-        def run(c):
-            return jax.lax.fori_loop(
-                0, w, lambda _, f: body(f),
-                c.reshape(rows * lane)).reshape(rows, lane)
-        return run
-
-    carry = jax.lax.switch(seg, [branch(b) for b in bodies], carry)
-    acc_ref[...] = carry
-    o_ref[...] = carry       # last grid step's write is the stage output
+        jax.lax.fori_loop(0, w_ref[m], repeat, 0)
 
 
 def mega_stage_kernel(x: jnp.ndarray, weights: jnp.ndarray,
-                      bodies: Sequence, *, interpret: bool = True
-                      ) -> jnp.ndarray:
+                      bodies: Sequence, lane: int, *,
+                      interpret: bool = True) -> jnp.ndarray:
     """Execute a fused stage as one kernel.
 
     ``x`` — flat f32 carry (the stage's ``data_size``); ``weights`` —
     (k,) i32 per-member trip counts (traced values, never statics);
-    ``bodies`` — k registered segment bodies in member order.
+    ``bodies`` — k registered ``(rows, lane) -> (rows, lane)`` segment
+    bodies in member order; ``lane`` — the carry layout's row width.
     """
     size = x.shape[0]
-    k = len(bodies)
-    lane = 128 if size % 128 == 0 else 8     # rounded sizes are 8-aligned
     rows = size // lane
-    kern = functools.partial(_mega_kernel, bodies=tuple(bodies),
-                             rows=rows, lane=lane)
+    kern = functools.partial(_mega_kernel, bodies=tuple(bodies))
     out = pl.pallas_call(
         kern,
-        grid=(k,),
-        in_specs=[pl.BlockSpec((1, 1), lambda s: (s, 0)),
-                  pl.BlockSpec((rows, lane), lambda s: (0, 0))],
-        out_specs=pl.BlockSpec((rows, lane), lambda s: (0, 0)),
+        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM),
+                  pl.BlockSpec(memory_space=pltpu.VMEM)],
+        out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((rows, lane), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((rows, lane), jnp.float32)],
         interpret=interpret,
-    )(weights.astype(jnp.int32).reshape(k, 1), x.reshape(rows, lane))
+    )(weights.astype(jnp.int32), x.reshape(rows, lane))
     return out.reshape(size)

@@ -6,7 +6,7 @@ import jax
 import jax.numpy as jnp
 
 from ..dispatch import default_interpret
-from .kernel import next_pow2, sort_net_kernel
+from .kernel import MIN_ROW, next_pow2, row_block, sort_net_kernel
 
 
 def _pad_max(dtype) -> jnp.ndarray:
@@ -22,13 +22,13 @@ def sort_rows(x: jnp.ndarray, *, block_m: int = 256,
               interpret: Optional[bool] = None) -> jnp.ndarray:
     """Sort each row of a (M, N) array ascending via the bitonic network;
     any M and N (rows pad to the block multiple, lengths to the next
-    power of two)."""
+    power of two and at least :data:`MIN_ROW` lanes)."""
     if interpret is None:
         interpret = default_interpret()
     M, N = x.shape
-    bm = min(block_m, M)
+    pn = max(next_pow2(N), MIN_ROW) - N
+    bm = row_block(M, N + pn, block_m)
     pm = (-M) % bm
-    pn = next_pow2(N) - N
     xp = x
     if pn:
         xp = jnp.concatenate(

@@ -13,9 +13,7 @@ import jax
 
 
 def _axis_type_kw(n: int) -> dict:
-    # AxisType only exists on newer jax; older versions default to Auto.
-    at = getattr(jax.sharding, "AxisType", None)
-    return {"axis_types": (at.Auto,) * n} if at is not None else {}
+    return {"axis_types": (jax.sharding.AxisType.Auto,) * n}
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:

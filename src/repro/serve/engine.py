@@ -275,12 +275,9 @@ class ResourceMonitor(threading.Thread):
                     int(f.read().split()[1]) * _page_size())
         except (OSError, ValueError, IndexError):  # pragma: no cover
             pass
-        try:
-            ms = jax.local_devices()[0].memory_stats()
-            if ms and "bytes_in_use" in ms:
-                self.device_bytes.append(int(ms["bytes_in_use"]))
-        except Exception:           # CPU backends expose no memory_stats
-            pass
+        ms = jax.local_devices()[0].memory_stats()   # None on CPU
+        if ms and "bytes_in_use" in ms:
+            self.device_bytes.append(int(ms["bytes_in_use"]))
 
     def run(self) -> None:
         while not self._halt.is_set():

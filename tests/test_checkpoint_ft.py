@@ -5,7 +5,6 @@ import os
 import jax
 import jax.numpy as jnp
 import numpy as np
-import pytest
 
 from repro.configs import ARCHS
 from repro.distributed.fault_tolerance import ResilientTrainLoop
@@ -75,9 +74,6 @@ def test_resilient_loop_recovers_from_failures(tmp_path, rng):
 
 def test_elastic_restore_with_new_shardings(tmp_path, rng):
     """A checkpoint restores onto a different mesh (elastic scaling)."""
-    if not hasattr(jax.sharding, "AxisType") or not hasattr(jax, "P"):
-        pytest.skip("explicit mesh axis types (jax.sharding.AxisType / "
-                    "jax.P) require jax >= 0.5")
     cfg, model, state = _tiny_state(rng)
     save_checkpoint(state, str(tmp_path), step=1)
     mesh = jax.make_mesh((1, 1), ("data", "model"),

@@ -14,7 +14,7 @@ except ImportError:        # property test skips; deterministic tests run
 
 from repro.api import ParamSpace, cache_stats, get_stack
 from repro.core import PopulationTuner, engine
-from repro.core.dag import Edge, ProxyDAG
+from repro.core.dag import Edge, ProxyDAG, _checksum
 from repro.core.dwarfs import ComponentParams
 from repro.core.dwarfs.base import REGISTRY
 from repro.core.proxy import ProxyBenchmark
@@ -306,3 +306,20 @@ def test_population_tuner_runs_generations_deterministically():
     assert res1.candidates_evaluated == res2.candidates_evaluated <= 18
     assert res1.final_accuracy["avg"] == res2.final_accuracy["avg"]
     assert res1.final_accuracy["avg"] >= res1.initial_accuracy["avg"]
+
+
+@pytest.mark.parametrize("n", [0, 1, 5, 1000, 1 << 12])
+def test_output_checksum_is_the_sum_in_a_width_independent_order(n):
+    """The DAG result's reduction: a lane reduced alone and inside a vmap
+    gives the same bits, and the value is the sum of the elements."""
+    x = jax.random.normal(jax.random.PRNGKey(n), (4, n))
+    one = jax.jit(_checksum)
+    many = jax.jit(jax.vmap(_checksum))(x)
+    for i in range(4):
+        assert np.asarray(one(x[i])).tobytes() == \
+            np.asarray(many[i]).tobytes()
+        np.testing.assert_allclose(float(one(x[i])),
+                                   np.sum(np.asarray(x[i], np.float64)),
+                                   rtol=1e-5, atol=1e-4)
+    ints = jax.numpy.arange(n, dtype=jax.numpy.int32)
+    assert int(_checksum(ints)) == n * (n - 1) // 2

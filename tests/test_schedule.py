@@ -24,6 +24,7 @@ from repro.core import schedule
 from repro.core.dag import Edge, ProxyDAG
 from repro.core.dwarfs import ComponentParams
 from repro.core.dwarfs.base import REGISTRY
+from repro.core.workloads import mega_chain
 
 POP = 3
 SIZE = 1024
@@ -357,15 +358,7 @@ def _mega_dag() -> ProxyDAG:
     """A private linear chain whose members all have registered megakernel
     segment bodies (quick_sort/hash/top_k/min_max) — fused under FUSE_ALL
     it lowers to a single mega-eligible stage."""
-    P = lambda w, **e: ComponentParams(data_size=2048, chunk_size=128,
-                                       weight=w, extra=e)
-    return ProxyDAG(
-        "mega_chain", {"src": 2048},
-        [Edge("quick_sort", ["src"], "a", P(2)),
-         Edge("hash", ["a"], "b", P(3, rounds=2)),
-         Edge("top_k", ["b"], "c", P(2, k=8)),
-         Edge("min_max", ["c"], "out", P(1))],
-        "out")
+    return mega_chain()
 
 
 def _run_plan(plan, dag):
