@@ -403,7 +403,7 @@ def phase_mesh(cfg: Dict[str, Any]) -> Dict[str, Any]:
 
             def spy(*a, _real=real):
                 out = _real(*a)
-                outs.append(out[0] if isinstance(out, tuple) else out)
+                outs.append(out)
                 return out
 
             setattr(stack, hook, spy)

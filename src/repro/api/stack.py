@@ -32,6 +32,7 @@ from __future__ import annotations
 
 import abc
 import dataclasses
+import threading
 import time
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
@@ -157,10 +158,16 @@ class RunReport:
 
     stack: str                   # registry name of the executing stack
     wall_s: float                # end-to-end wall time (incl. compile)
-    io_bytes: float              # host<->device traffic ("disk I/O" analog)
+    #: modelled host traffic ("disk I/O" analog): each spilled buffer
+    #: counts twice (write + read back), sources once
+    io_bytes: float
     result: Any = None           # the executable's output pytree
     batch: int = 1               # number of rng instances executed
     result_bytes: float = 0.0    # size of the output pytree
+    #: bytes the stack itself copied host-to-device (``jnp.asarray`` of a
+    #: host array) and device-to-host (``np.asarray`` of a device array)
+    h2d_bytes: float = 0.0
+    d2h_bytes: float = 0.0
     #: the executed ProxyDAG when the run came from a DAG-bearing
     #: executable (None for raw callables) — lets
     #: ``repro.api.fingerprint(report)`` recover the measured channel
@@ -181,7 +188,49 @@ class RunReport:
         return {"stack": self.stack, "wall_s": self.wall_s,
                 "io_bytes": self.io_bytes, "batch": self.batch,
                 "result_bytes": self.result_bytes,
+                "h2d_bytes": self.h2d_bytes, "d2h_bytes": self.d2h_bytes,
                 "throughput": self.throughput}
+
+
+class _Traffic:
+    """Host traffic of one run: the modelled spill (``io``) and the bytes
+    the stack copied itself, by direction.  Copies go through :meth:`up`
+    and :meth:`down`, which count them; the population path uploads on
+    worker threads, hence the lock."""
+
+    def __init__(self):
+        self.io = 0.0
+        self.h2d = 0.0
+        self.d2h = 0.0
+        self._lock = threading.Lock()
+
+    def up(self, x, dtype=None) -> jax.Array:
+        """``jnp.asarray(x)``, counting a host array's bytes."""
+        if not isinstance(x, jax.Array):
+            x = np.asarray(x, dtype)
+            with self._lock:
+                self.h2d += x.nbytes
+        return jnp.asarray(x, dtype)
+
+    def down(self, x) -> np.ndarray:
+        """``np.asarray(x)``, counting a device array's bytes."""
+        host = np.asarray(x)
+        if isinstance(x, jax.Array):
+            with self._lock:
+                self.d2h += host.nbytes
+        return host
+
+    def report(self, stack: str, wall_s: float, result: Any, batch: int,
+               dag: Any = None) -> "RunReport":
+        return RunReport(stack=stack, wall_s=wall_s, io_bytes=self.io,
+                         result=result, batch=batch,
+                         result_bytes=_tree_bytes(result),
+                         h2d_bytes=self.h2d, d2h_bytes=self.d2h, dag=dag)
+
+
+#: a program span: a host annotation in the profiler's trace, on the
+#: device trace's clock (a cheap no-op while no profiler runs)
+_span = jax.profiler.TraceAnnotation
 
 
 def _tree_bytes(out: Any) -> float:
@@ -228,11 +277,11 @@ def _default_rng(rng: Optional[jax.Array]) -> jax.Array:
     return jax.random.PRNGKey(0) if rng is None else rng
 
 
-def _take_candidates(dynb: Tuple, indices) -> Tuple:
+def _take_candidates(dynb: Tuple, indices, io: _Traffic) -> Tuple:
     """Gather one bucket's slice of a stacked dyn pytree (leading
     candidate axis) — shapes depend only on the bucket size, so every
     same-size bucket reuses one compiled executable."""
-    sel = jnp.asarray(np.asarray(indices), jnp.int32)
+    sel = io.up(indices, np.int32)
     return jax.tree_util.tree_map(lambda v: v[sel], dynb)
 
 
@@ -244,10 +293,12 @@ def _take_candidates(dynb: Tuple, indices) -> Tuple:
 class Stack(abc.ABC):
     """One software-stack execution model.
 
-    Subclasses implement ``_execute(fn, args) -> (result, io_bytes)`` for
-    raw-fn/workload executables; coercion, timing, batching and reporting
-    are shared.  DAG executables take the compile-once fast path instead:
-    they lower to an ``ExecutionPlan`` (``repro.core.schedule.lower`` —
+    Subclasses implement ``_execute(fn, args, io) -> result`` for
+    raw-fn/workload executables, counting the host copies they make in
+    ``io`` (a :class:`_Traffic`); coercion, timing, batching and
+    reporting are shared.  DAG executables take the compile-once fast
+    path instead: they lower to an ``ExecutionPlan``
+    (``repro.core.schedule.lower`` —
     fused stages under the live ``REPRO_FUSION_THRESHOLD``) and
     ``run``/``run_batch`` fetch a cached parametric executable via
     ``_compiled_plan``, so a stack that needs its execution model applied
@@ -261,9 +312,9 @@ class Stack(abc.ABC):
     name: str = "abstract"
 
     @abc.abstractmethod
-    def _execute(self, fn: Callable, args: Tuple) -> Tuple[Any, float]:
-        """Run ``fn(*args)`` under this execution model.
-        Returns ``(result, io_bytes)``."""
+    def _execute(self, fn: Callable, args: Tuple, io: _Traffic) -> Any:
+        """Run ``fn(*args)`` under this execution model; host traffic
+        goes to ``io``."""
 
     # -- compiled plan executables ------------------------------------------
 
@@ -323,20 +374,20 @@ class Stack(abc.ABC):
                 return pfn(rng, dyn)
         return jax.jit(f, donate_argnums=_donate_argnums())
 
-    def _dag_run(self, dag: ProxyDAG, rng: jax.Array) -> Tuple[Any, float]:
+    def _dag_run(self, dag: ProxyDAG, rng: jax.Array, io: _Traffic) -> Any:
         plan = plans.lower(dag)
         out = self._compiled_plan(plan, batch=False)(rng,
                                                      dag.dynamic_params())
         jax.block_until_ready(out)
-        return out, 0.0
+        return out
 
-    def _dag_run_batch(self, dag: ProxyDAG, rngs: jax.Array
-                       ) -> Tuple[Any, float]:
+    def _dag_run_batch(self, dag: ProxyDAG, rngs: jax.Array,
+                       io: _Traffic) -> Any:
         plan = plans.lower(dag)
         out = self._compiled_plan(plan, batch=True)(rngs,
                                                     dag.dynamic_params())
         jax.block_until_ready(out)
-        return out, 0.0
+        return out
 
     # -- population evaluation (one compiled call per weight bucket) ---------
 
@@ -399,17 +450,16 @@ class Stack(abc.ABC):
         return jax.jit(f)
 
     def _population_call(self, fn: Callable, rng: jax.Array,
-                         dynb: Tuple) -> Tuple[Any, float]:
+                         dynb: Tuple) -> Any:
         """One bucket's executable call (placement hook — see SparkStack).
         Deliberately *not* synced: the bucket loop dispatches every
         stratum and lets the assembly's host transfer force completion,
         overlapping per-bucket Python overhead with device compute."""
-        return fn(rng, dynb), 0.0
+        return fn(rng, dynb)
 
     def _dag_run_population(self, dag: ProxyDAG, rng: jax.Array,
-                            dynb: Tuple, n: int,
-                            bucket_size: Optional[int] = None
-                            ) -> Tuple[Any, float]:
+                            dynb: Tuple, n: int, io: _Traffic,
+                            bucket_size: Optional[int] = None) -> Any:
         """Bucketed population execution: candidates stratified by total
         weighted cost run one vmapped call per bucket, so each bucket's
         batched ``while`` trips only to its own maximum instead of the
@@ -420,54 +470,54 @@ class Stack(abc.ABC):
         unfused (``plans.lower_population``): per-edge loops give the
         schedule its per-edge trip bounds, and a fused switch under a
         batched candidate axis would execute every branch per trip."""
-        plan = plans.lower_population(dag)
-        sched = plan.bucket_schedule(dynb, bucket_size)
-        if sched.bucket_size == 1:
-            # fully stratified schedule (the single-device default): every
-            # candidate runs exactly its own trips through an *unbatched*
-            # parametric executable (no batched-while masking overhead),
-            # strata dispatched over a small host thread pool — the CPU
-            # analogue of sharding the candidate axis over a mesh
-            fn = self._compiled_plan(plan, batch=False)
-            host_dynb = jax.tree_util.tree_map(np.asarray, dynb)
-
-            def one(i: int):
-                dyn_i = jax.tree_util.tree_map(
-                    lambda v: jnp.asarray(v[i]), host_dynb)
-                return self._population_call(fn, rng, dyn_i)
-
-            order = [int(b.indices[0]) for b in sched.buckets]
-            workers = plans.population_workers()
-            if (workers > 1 and len(order) > 1 and
-                    type(self)._population_call is Stack._population_call):
-                from concurrent.futures import ThreadPoolExecutor
-                with ThreadPoolExecutor(max_workers=workers) as pool:
-                    results = list(zip(order, pool.map(one, order)))
+        with _span("stack.schedule"):
+            plan = plans.lower_population(dag)
+            sched = plan.bucket_schedule(dynb, bucket_size)
+            single = sched.bucket_size == 1
+            if single:
+                # fully stratified schedule (the single-device default):
+                # every candidate runs exactly its own trips through an
+                # *unbatched* parametric executable (no batched-while
+                # masking overhead), strata dispatched over a small host
+                # thread pool — the CPU analogue of sharding the
+                # candidate axis over a mesh
+                fn = self._compiled_plan(plan, batch=False)
+                host_dynb = jax.tree_util.tree_map(io.down, dynb)
             else:
-                results = [(i, one(i)) for i in order]
-            out_np = None
-            io_bytes = 0.0
-            for i, (res, io_b) in results:     # host transfer = the sync
-                io_bytes += io_b
-                host = np.asarray(res)
-                if out_np is None:
-                    out_np = np.empty((sched.n,) + host.shape, host.dtype)
-                out_np[i] = host
-            return jnp.asarray(out_np), io_bytes
-        fn = self._compiled_plan_population(plan, sched.bucket_size)
-        results, io_bytes = [], 0.0
-        for b in sched.buckets:
-            res, io_b = self._population_call(
-                fn, rng, _take_candidates(dynb, b.indices))
-            io_bytes += io_b
-            results.append((b, res))
+                fn = self._compiled_plan_population(plan, sched.bucket_size)
+
+        def dispatch(k: int):
+            b = sched.buckets[k]
+            with _span("stack.dispatch", bucket=k):
+                if single:
+                    i = int(b.indices[0])
+                    dyn = jax.tree_util.tree_map(lambda v: io.up(v[i]),
+                                                 host_dynb)
+                else:
+                    dyn = _take_candidates(dynb, b.indices, io)
+                return b, self._population_call(fn, rng, dyn)
+
+        order = range(len(sched.buckets))
+        workers = plans.population_workers()
+        if (single and workers > 1 and len(order) > 1 and
+                type(self)._population_call is Stack._population_call):
+            from concurrent.futures import ThreadPoolExecutor
+            with ThreadPoolExecutor(max_workers=workers) as pool:
+                results = list(pool.map(dispatch, order))
+        else:
+            results = [dispatch(k) for k in order]
         out_np = None
-        for b, res in results:                 # host transfer = the sync
-            host = np.asarray(res)
-            if out_np is None:
-                out_np = np.empty((sched.n,) + host.shape[1:], host.dtype)
-            out_np[b.indices[:b.valid]] = host[:b.valid]
-        return jnp.asarray(out_np), io_bytes
+        with _span("stack.gather"):
+            for b, res in results:             # host transfer = the sync
+                host = io.down(res)
+                if single:
+                    host = host[None]
+                if out_np is None:
+                    out_np = np.empty((sched.n,) + host.shape[1:],
+                                      host.dtype)
+                out_np[b.indices[:b.valid]] = host[:b.valid]
+        with _span("stack.assemble"):
+            return io.up(out_np)
 
     def _coerce_population(self, dag: ProxyDAG, candidates: Any,
                            space: Any) -> Tuple[Tuple, int]:
@@ -497,13 +547,14 @@ class Stack(abc.ABC):
             rng: Optional[jax.Array] = None) -> RunReport:
         """Execute anything on this stack and report uniformly."""
         dag = _extract_dag(executable)
+        io = _Traffic()
         t0 = time.perf_counter()
         if dag is not None:
             if args:
                 raise TypeError(
                     f"{type(executable).__name__} executables take no "
                     f"positional args; pass the PRNG key as rng=...")
-            result, io_bytes = self._dag_run(dag, _default_rng(rng))
+            result = self._dag_run(dag, _default_rng(rng), io)
         else:
             fn, fargs = _as_fn(executable, args)
             if rng is not None:
@@ -512,11 +563,9 @@ class Stack(abc.ABC):
                                     "inputs; rng= only applies to DAG or "
                                     "rng-driven fn executables")
                 fargs = (*fargs, rng)    # fn(*args, rng) convention
-            result, io_bytes = self._execute(fn, fargs)
+            result = self._execute(fn, fargs, io)
         wall = time.perf_counter() - t0
-        return RunReport(stack=self.name, wall_s=wall, io_bytes=io_bytes,
-                         result=result, batch=1,
-                         result_bytes=_tree_bytes(result), dag=dag)
+        return io.report(self.name, wall, result, 1, dag)
 
     def run_batch(self, executable: Any,
                   rngs: jax.Array) -> RunReport:
@@ -527,15 +576,14 @@ class Stack(abc.ABC):
             raise TypeError("run_batch needs an rng-driven executable "
                             "(ProxyDAG/ProxyBenchmark/ProxySpec or fn(rng))")
         batch = int(rngs.shape[0])
+        io = _Traffic()
         t0 = time.perf_counter()
         if dag is not None:
-            result, io_bytes = self._dag_run_batch(dag, rngs)
+            result = self._dag_run_batch(dag, rngs, io)
         else:
-            result, io_bytes = self._execute_batch(executable, rngs)
+            result = self._execute_batch(executable, rngs, io)
         wall = time.perf_counter() - t0
-        return RunReport(stack=self.name, wall_s=wall, io_bytes=io_bytes,
-                         result=result, batch=batch,
-                         result_bytes=_tree_bytes(result), dag=dag)
+        return io.report(self.name, wall, result, batch, dag)
 
     def run_population(self, executable: Any, candidates: Any, *,
                        rng: Optional[jax.Array] = None,
@@ -564,17 +612,16 @@ class Stack(abc.ABC):
                 f"ProxyBenchmark / ProxySpec), got "
                 f"{type(executable).__name__}")
         dynb, n = self._coerce_population(dag, candidates, space)
+        io = _Traffic()
         t0 = time.perf_counter()
-        result, io_bytes = self._dag_run_population(
-            dag, _default_rng(rng), dynb, n, bucket_size=bucket_size)
+        result = self._dag_run_population(
+            dag, _default_rng(rng), dynb, n, io, bucket_size=bucket_size)
         wall = time.perf_counter() - t0
-        return RunReport(stack=self.name, wall_s=wall, io_bytes=io_bytes,
-                         result=result, batch=n,
-                         result_bytes=_tree_bytes(result), dag=dag)
+        return io.report(self.name, wall, result, n, dag)
 
-    def _execute_batch(self, fn: Callable, rngs: jax.Array
-                       ) -> Tuple[Any, float]:
-        return self._execute(jax.vmap(fn), (rngs,))
+    def _execute_batch(self, fn: Callable, rngs: jax.Array,
+                       io: _Traffic) -> Any:
+        return self._execute(jax.vmap(fn), (rngs,), io)
 
     def __repr__(self) -> str:
         return f"<Stack:{self.name}>"
@@ -594,10 +641,10 @@ class OpenMPStack(Stack):
 
     name = "openmp"
 
-    def _execute(self, fn, args):
+    def _execute(self, fn, args, io):
         out = jax.jit(fn)(*args)
         jax.block_until_ready(out)
-        return out, 0.0
+        return out
 
 
 class _MeshStack(Stack):
@@ -699,22 +746,22 @@ class MPIStack(_MeshStack):
 
     _single = _pmean_floats
 
-    def _execute(self, fn, args):
+    def _execute(self, fn, args, io):
         spmd = self._per_device(lambda *a: self._pmean_floats(fn(*a)),
                                 P(), P())
         out = jax.jit(spmd)(*args)
         jax.block_until_ready(out)
-        return out, 0.0
+        return out
 
-    def _execute_batch(self, fn, rngs):
+    def _execute_batch(self, fn, rngs, io):
         n = self.mesh.devices.size
         batch = int(rngs.shape[0])
         if batch % n != 0:  # pragma: no cover
-            return self._execute(jax.vmap(fn), (rngs,))
+            return self._execute(jax.vmap(fn), (rngs,), io)
         spmd = self._per_device(jax.vmap(fn), P(self.axis), P(self.axis))
         out = jax.jit(spmd)(rngs)
         jax.block_until_ready(out)
-        return out, 0.0
+        return out
 
 
 class SparkStack(_MeshStack):
@@ -732,7 +779,7 @@ class SparkStack(_MeshStack):
             return P(self.axis)
         return P()
 
-    def _execute(self, fn, args):
+    def _execute(self, fn, args, io):
         with self.mesh:
             placed = tuple(
                 jax.device_put(a, NamedSharding(self.mesh, self._spec_for(a)))
@@ -740,17 +787,17 @@ class SparkStack(_MeshStack):
                 for a in args)
             out = jax.jit(fn)(*placed)
             jax.block_until_ready(out)
-        return out, 0.0
+        return out
 
-    def _dag_run(self, dag, rng):
+    def _dag_run(self, dag, rng, io):
         fn = self._compiled_plan(plans.lower(dag), batch=False)
         with self.mesh:
             rng = jax.device_put(rng, NamedSharding(self.mesh, P()))
             out = fn(rng, dag.dynamic_params())
             jax.block_until_ready(out)
-        return out, 0.0
+        return out
 
-    def _dag_run_batch(self, dag, rngs):
+    def _dag_run_batch(self, dag, rngs, io):
         fn = self._compiled_plan(plans.lower(dag), batch=True)
         with self.mesh:
             # shard the rng batch over the workers (the "RDD partitions")
@@ -758,7 +805,7 @@ class SparkStack(_MeshStack):
                 rngs, NamedSharding(self.mesh, self._spec_for(rngs)))
             out = fn(rngs, dag.dynamic_params())
             jax.block_until_ready(out)
-        return out, 0.0
+        return out
 
     def _population_call(self, fn, rng, dynb):
         from ..distributed.sharding import bucket_shardings
@@ -770,8 +817,7 @@ class SparkStack(_MeshStack):
                 dynb, bucket_shardings(self.mesh, dynb,
                                        prefer=(self.axis,)))
             rng = jax.device_put(rng, NamedSharding(self.mesh, P()))
-            out = fn(rng, dynb)
-        return out, 0.0
+            return fn(rng, dynb)
 
     def _serve_call(self, fn, rngs, dynb):
         from ..distributed.sharding import serve_shardings
@@ -788,30 +834,54 @@ class SparkStack(_MeshStack):
 class HadoopStack(Stack):
     """Staged map -> host-materialized intermediate ("HDFS spill") ->
     reduce.  DAG executables run edge-by-edge with every intermediate node
-    round-tripped through host memory; ``io_bytes`` counts both directions
-    (the paper's disk-I/O bandwidth analog)."""
+    round-tripped through host memory; ``io_bytes`` models both directions
+    (the paper's disk-I/O bandwidth analog) and ``h2d_bytes`` /
+    ``d2h_bytes`` count the copies made."""
 
     name = "hadoop"
 
     def __init__(self, n_chunks: int = 8):
         self.n_chunks = n_chunks
 
-    def _execute(self, fn, args):
+    def _execute(self, fn, args, io):
         # opaque fn: run, then spill the result through host memory
         out = jax.jit(fn)(*args)
         jax.block_until_ready(out)
-        hosts = jax.tree_util.tree_map(lambda x: np.asarray(x), out)
-        io_bytes = _tree_bytes(hosts) * 2.0          # write + read back
-        result = jax.tree_util.tree_map(jnp.asarray, hosts)
-        return result, io_bytes
+        hosts = jax.tree_util.tree_map(io.down, out)
+        io.io += _tree_bytes(hosts) * 2.0            # write + read back
+        return jax.tree_util.tree_map(io.up, hosts)
 
-    def _dag_run(self, dag, rng):
-        return self._run_stages(dag, rng, vmap=False)
+    def _dag_run(self, dag, rng, io):
+        return self._run_stages(dag, rng, False, io)
 
-    def _dag_run_batch(self, dag, rngs):
-        return self._run_stages(dag, rngs, vmap=True)
+    def _dag_run_batch(self, dag, rngs, io):
+        return self._run_stages(dag, rngs, True, io)
 
-    def _dag_run_population(self, dag, rng, dynb, n, bucket_size=None):
+    @staticmethod
+    def _spilled(io: _Traffic, inputs, call: Callable, download: bool = True,
+                 **ids) -> Any:
+        """One stage between host copies, under a ``hadoop.stage`` span
+        (``ids`` name it): upload ``inputs`` (a pytree of host arrays),
+        ``call`` them, wait for the device, and copy the output back to
+        the host unless ``download`` is off."""
+        with _span("hadoop.stage", **ids):
+            with _span("hadoop.h2d"):
+                args = jax.tree_util.tree_map(io.up, inputs)
+            with _span("hadoop.dispatch"):
+                out = call(*args)
+                if download:
+                    # queue the copy behind the stage, as a bare np.asarray
+                    # would, so the wait below adds no host round trip
+                    # before the copy starts
+                    out.copy_to_host_async()
+            with _span("hadoop.wait"):
+                jax.block_until_ready(out)
+            if not download:
+                return out
+            with _span("hadoop.d2h"):
+                return io.down(out)
+
+    def _dag_run_population(self, dag, rng, dynb, n, io, bucket_size=None):
         """Staged population sweep over the plan's bucket schedule: every
         candidate's intermediates spill through host memory per *fused
         stage* (the population multiplies the "HDFS" traffic — at stage,
@@ -820,52 +890,53 @@ class HadoopStack(Stack):
         bucket's own maxima.  Sources are generated once and shared —
         candidates differ only in dynamic params, so source nodes stay
         unbatched until a stage first writes a node."""
-        plan = plans.lower(dag)
-        sched = plan.bucket_schedule(dynb, bucket_size)
-        nb = sched.bucket_size
-        init, stages, finalize = plan.stages_parametric()
-        pkey = plan.structure_key()
-        src_key = tuple(sorted(plan.sources.items()))
-        jinit = self._cached_stage(("init", False, src_key), lambda: init)
-        io_bytes = 0.0
-        shared: Dict[str, np.ndarray] = {}
-        for k, v in jinit(rng).items():              # shared "HDFS read"
-            host = np.asarray(v)
-            io_bytes += host.nbytes
-            shared[k] = host
+        with _span("hadoop.init"):
+            plan = plans.lower(dag)
+            sched = plan.bucket_schedule(dynb, bucket_size)
+            nb = sched.bucket_size
+            init, stages, finalize = plan.stages_parametric()
+            pkey = plan.structure_key()
+            src_key = tuple(sorted(plan.sources.items()))
+            jinit = self._cached_stage(("init", False, src_key),
+                                       lambda: init)
+            shared = {k: io.down(v)                  # shared "HDFS read"
+                      for k, v in jinit(rng).items()}
+            io.io += sum(h.nbytes for h in shared.values())
         out_np: Optional[np.ndarray] = None
-        for b in sched.buckets:
-            sub = _take_candidates(dynb, b.indices)
+        for bi, b in enumerate(sched.buckets):
+            sub = _take_candidates(dynb, b.indices, io)
             stage_dyns = plan.stage_dyn_tuples(sub)
             nodes: Dict[str, np.ndarray] = dict(shared)
             batched: Dict[str, bool] = {}
             for si, (srcs, dst, stage, stage_key) in enumerate(stages):
-                xs = [jnp.asarray(nodes[s]) for s in srcs]
-                x_axes = [0 if batched.get(s) else None for s in srcs]
-                prev = jnp.asarray(nodes[dst]) if dst in nodes else None
+                prev = nodes.get(dst)
+                x_axes = tuple(0 if batched.get(s) else None for s in srcs)
                 prev_ax = 0 if batched.get(dst) else None
-                sfn = self._cached_stage(
-                    ("pstage", nb, tuple(x_axes), prev is None, prev_ax,
-                     stage_key),
-                    lambda s=stage, xa=tuple(x_axes), hp=prev is None,
-                    pa=prev_ax: jax.vmap(
-                        s, in_axes=(None, list(xa), None if hp else pa, 0)))
-                out = sfn(rng, xs, prev, stage_dyns[si])
-                host = np.asarray(out)               # per-candidate spill
-                io_bytes += host.nbytes * 2.0        # write + read back
+
+                def call(xs, prev, stage=stage, stage_key=stage_key,
+                         xa=x_axes, pa=prev_ax, dyn=stage_dyns[si]):
+                    hp = prev is None
+                    sfn = self._cached_stage(
+                        ("pstage", nb, xa, hp, pa, stage_key),
+                        lambda: jax.vmap(stage, in_axes=(
+                            None, list(xa), None if hp else pa, 0)))
+                    return sfn(rng, xs, prev, dyn)
+
+                host = self._spilled(io, ([nodes[s] for s in srcs], prev),
+                                     call, bucket=bi, stage=si)
+                io.io += host.nbytes * 2.0           # write + read back
                 nodes[dst] = host
                 batched[dst] = True
             fin_axes = {k: 0 if batched.get(k) else None for k in nodes}
             jfin = self._cached_stage(
                 ("pfinalize", nb, tuple(sorted(fin_axes.items())), pkey),
                 lambda ax=fin_axes: jax.vmap(finalize, in_axes=(ax,)))
-            res = jfin({k: jnp.asarray(v) for k, v in nodes.items()})
-            jax.block_until_ready(res)
-            host = np.asarray(res)
+            host = self._spilled(io, (nodes,), jfin, bucket=bi,
+                                 stage="finalize")
             if out_np is None:
                 out_np = np.empty((sched.n,) + host.shape[1:], host.dtype)
             out_np[b.indices[:b.valid]] = host[:b.valid]
-        return jnp.asarray(out_np), io_bytes
+        return io.up(out_np)
 
     def _cached_stage(self, key: Tuple, make: Callable,
                       cost: float = 0.0) -> Callable:
@@ -882,48 +953,48 @@ class HadoopStack(Stack):
         return get_pool().get(self.exec_domain(), self._exec_key(key), build,
                               cost=cost)
 
-    def _run_stages(self, dag: ProxyDAG, rng: jax.Array, vmap: bool
-                    ) -> Tuple[Any, float]:
+    def _run_stages(self, dag: ProxyDAG, rng: jax.Array, vmap: bool,
+                    io: _Traffic) -> Any:
         """Stage-by-stage execution with host-spilled intermediates at
         *fused-stage* granularity: a fused chain of low-weight edges
         spills once, not once per edge — the plan lowering cuts the
         "HDFS" round-trip volume.  Each stage's jitted form is cached
         under its structural key, so repeated runs — and dynamic-param
         sweeps — reuse every per-stage compile."""
-        plan = plans.lower(dag)
-        init, stages, finalize = plan.stages_parametric()
-        pkey = plan.structure_key()
-        stage_dyns = plan.stage_dyn_tuples(dag.dynamic_params())
-        src_key = tuple(sorted(plan.sources.items()))
-        jinit = self._cached_stage(
-            ("init", vmap, src_key),
-            lambda: jax.vmap(init) if vmap else init)
-        sources = jinit(rng)
-        io_bytes = 0.0
-        nodes: Dict[str, np.ndarray] = {}
-        for k, v in sources.items():                 # "HDFS read" of inputs
-            host = np.asarray(v)
-            io_bytes += host.nbytes
-            nodes[k] = host
+        with _span("hadoop.init"):
+            plan = plans.lower(dag)
+            init, stages, finalize = plan.stages_parametric()
+            pkey = plan.structure_key()
+            stage_dyns = plan.stage_dyn_tuples(dag.dynamic_params())
+            src_key = tuple(sorted(plan.sources.items()))
+            jinit = self._cached_stage(
+                ("init", vmap, src_key),
+                lambda: jax.vmap(init) if vmap else init)
+            nodes: Dict[str, np.ndarray] = {   # "HDFS read" of inputs
+                k: io.down(v) for k, v in jinit(rng).items()}
+            io.io += sum(h.nbytes for h in nodes.values())
         for si, (srcs, dst, stage, stage_key) in enumerate(stages):  # map tasks
-            xs = [jnp.asarray(nodes[s]) for s in srcs]
-            prev = jnp.asarray(nodes[dst]) if dst in nodes else None
-            sfn = self._cached_stage(
-                ("stage", vmap, prev is None, stage_key),
-                lambda s=stage, hp=prev is None: (
-                    jax.vmap(s, in_axes=(0, 0, None if hp else 0, None))
-                    if vmap else s),
-                cost=float(plan.stages[si].cost))
-            out = sfn(rng, xs, prev, stage_dyns[si])
-            host = np.asarray(out)                   # spill to "disk"
-            io_bytes += host.nbytes * 2.0            # write + read back
+            prev = nodes.get(dst)
+
+            def call(xs, prev, si=si, stage=stage, stage_key=stage_key):
+                hp = prev is None
+                sfn = self._cached_stage(
+                    ("stage", vmap, hp, stage_key),
+                    lambda: (jax.vmap(stage, in_axes=(0, 0, None if hp
+                                                      else 0, None))
+                             if vmap else stage),
+                    cost=float(plan.stages[si].cost))
+                return sfn(rng, xs, prev, stage_dyns[si])
+
+            host = self._spilled(io, ([nodes[s] for s in srcs], prev), call,
+                                 stage=si)           # spill to "disk"
+            io.io += host.nbytes * 2.0               # write + read back
             nodes[dst] = host
         jfin = self._cached_stage(
             ("finalize", vmap, pkey),
             lambda: jax.vmap(finalize) if vmap else finalize)
-        result = jfin({k: jnp.asarray(v) for k, v in nodes.items()})
-        jax.block_until_ready(result)
-        return result, io_bytes
+        return self._spilled(io, (nodes,), jfin, download=False,
+                             stage="finalize")
 
     # -- seed-compatible chunked map/reduce ---------------------------------
 
@@ -933,25 +1004,21 @@ class HadoopStack(Stack):
         """Chunked map -> host-spilled shuffle -> reduce (the seed's
         ``hadoop()`` execution shape, now reporting uniformly)."""
         n_chunks = n_chunks or self.n_chunks
+        io = _Traffic()
         t0 = time.perf_counter()
         n = data.shape[0] // n_chunks * n_chunks
-        chunks = np.asarray(data[:n]).reshape(n_chunks, -1, *data.shape[1:])
+        chunks = io.down(data[:n]).reshape(n_chunks, -1, *data.shape[1:])
         jmap = jax.jit(map_fn)
-        io_bytes = 0.0
         intermediates: List[np.ndarray] = []
         for c in chunks:                              # map tasks
-            out = jmap(jnp.asarray(c))
-            host = np.asarray(out)                    # spill to "disk"
-            io_bytes += host.nbytes * 2.0             # write + read back
+            host = io.down(jmap(io.up(c)))            # spill to "disk"
+            io.io += host.nbytes * 2.0                # write + read back
             intermediates.append(host)
-        shuffled = jnp.asarray(
+        shuffled = io.up(
             np.concatenate([i.reshape(-1) for i in intermediates]))
         result = jax.jit(reduce_fn)(shuffled)         # reduce task
         jax.block_until_ready(result)
-        wall = time.perf_counter() - t0
-        return RunReport(stack=self.name, wall_s=wall, io_bytes=io_bytes,
-                         result=result, batch=1,
-                         result_bytes=_tree_bytes(result))
+        return io.report(self.name, time.perf_counter() - t0, result, 1)
 
 
 # ---------------------------------------------------------------------------

@@ -172,15 +172,16 @@ def _edge_out(e: Edge, ei: int, x: jnp.ndarray, rng: jax.Array,
         if extra_dyn:
             p = p.replace(extra={**p.extra, **extra_dyn})
     w = dyn["weight"] if dyn and "weight" in dyn else p.weight
-    x0 = fit_buffer(x, p.data_size)
-    if isinstance(w, int) and w == 0:        # tuner pruned this edge
-        return x0
+    with jax.named_scope(e.component):      # names the edge's device ops
+        x0 = fit_buffer(x, p.data_size)
+        if isinstance(w, int) and w == 0:    # tuner pruned this edge
+            return x0
 
-    def body(i, out):
-        r = jax.random.fold_in(rng, 10_000 + 131 * ei + i)
-        return fit_buffer(comp(out, p, r), p.data_size)
+        def body(i, out):
+            r = jax.random.fold_in(rng, 10_000 + 131 * ei + i)
+            return fit_buffer(comp(out, p, r), p.data_size)
 
-    return jax.lax.fori_loop(0, w, body, x0)
+        return jax.lax.fori_loop(0, w, body, x0)
 
 
 def _accumulate(prev: Optional[jnp.ndarray], out: jnp.ndarray) -> jnp.ndarray:

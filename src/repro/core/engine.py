@@ -64,10 +64,13 @@ _PIECE_DOM = get_pool().register("engine:piece", _PIECE_CACHE, kind="report",
 _EXEC_DOM = get_pool().register("engine:exec", _EXEC_CACHE,
                                 kind="executable", cap=_EXEC_CACHE_CAP)
 
-_STATS = {"compiles": 0, "traces": 0, "hits": 0, "exec_compiles": 0}
+#: ``analyze_s`` — seconds spent in :func:`_analyze` (lowering, compiling
+#: and reading a body's HLO for its cost)
+_STATS = {"compiles": 0, "traces": 0, "hits": 0, "exec_compiles": 0,
+          "analyze_s": 0.0}
 
 
-def stats() -> Dict[str, int]:
+def stats() -> Dict[str, float]:
     """Counters of engine compile/trace activity (monotonic)."""
     return dict(_STATS)
 
@@ -89,8 +92,12 @@ def clear_caches() -> None:
 def _analyze(fn: Callable, args: Tuple) -> CostReport:
     """Lower+compile ``fn`` (abstract args are fine) and analyze its HLO."""
     _STATS["compiles"] += 1
-    compiled = jax.jit(fn).lower(*args).compile()
-    return analyze_hlo_text(compiled.as_text())
+    t0 = time.perf_counter()
+    with jax.profiler.TraceAnnotation("engine.analyze"):
+        compiled = jax.jit(fn).lower(*args).compile()
+        rep = analyze_hlo_text(compiled.as_text())
+    _STATS["analyze_s"] += time.perf_counter() - t0
+    return rep
 
 
 def _rng_spec() -> jax.Array:

@@ -290,10 +290,12 @@ def _fused_out(members: Sequence[Tuple[int, Edge]], x: jnp.ndarray,
     for m, (ei, e) in enumerate(members):
         comp = get_component(e.component)
 
-        def branch(operand, _comp=comp, _p=ps[m], _ei=ei):
+        def branch(operand, _comp=comp, _p=ps[m], _ei=ei,
+                   _name=e.component):
             carry, local = operand
-            r = jax.random.fold_in(rng, 10_000 + 131 * _ei + local)
-            return fit_buffer(_comp(carry, _p, r), size)
+            with jax.named_scope(_name):
+                r = jax.random.fold_in(rng, 10_000 + 131 * _ei + local)
+                return fit_buffer(_comp(carry, _p, r), size)
 
         branches.append(branch)
 

@@ -101,5 +101,6 @@ def flash_attention_kernel(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, 1), jnp.float32),
         ],
+        name="flash_attention",
         interpret=interpret,
     )(q, k, v)

@@ -35,5 +35,6 @@ def hash_mix_kernel(x: jnp.ndarray, *, rounds: int = 2, block: int = 1024,
         in_specs=[pl.BlockSpec((bm, N), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((bm, N), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((M, N), jnp.uint32),
+        name="hash_mix",
         interpret=interpret,
     )(x)

@@ -44,5 +44,6 @@ def matmul_kernel(a: jnp.ndarray, b: jnp.ndarray, *, block_m: int = 128,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), a.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
+        name="matmul",
         interpret=interpret,
     )(a, b)

@@ -70,6 +70,7 @@ def mega_stage_kernel(x: jnp.ndarray, weights: jnp.ndarray,
                   pl.BlockSpec(memory_space=pltpu.VMEM)],
         out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((rows, lane), jnp.float32),
+        name="megakernel",
         interpret=interpret,
     )(weights.astype(jnp.int32), x.reshape(rows, lane))
     return out.reshape(size)

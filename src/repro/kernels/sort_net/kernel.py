@@ -93,5 +93,6 @@ def sort_net_kernel(x: jnp.ndarray, *, block_m: int = 256,
         in_specs=[pl.BlockSpec((bm, N), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((bm, N), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((M, N), x.dtype),
+        name="sort_net",
         interpret=interpret,
     )(x)

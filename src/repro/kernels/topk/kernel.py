@@ -44,5 +44,6 @@ def topk_kernel(x: jnp.ndarray, k: int, *, block_m: int = 256,
                    pl.BlockSpec((bm, k), lambda i: (i, 0))),
         out_shape=(jax.ShapeDtypeStruct((M, k), x.dtype),
                    jax.ShapeDtypeStruct((M, k), jnp.int32)),
+        name="topk",
         interpret=interpret,
     )(x)

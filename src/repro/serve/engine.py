@@ -32,8 +32,8 @@ compile-once/run-many machinery:
 * Every request's queue wait, service time, total latency and terminal
   status are recorded; the :class:`ServeReport` emits P50/P95/P99, time
   to first result, sustained throughput, the micro-batch histogram,
-  cold-dispatch / retry / deadline-miss / degradation accounting and a
-  :class:`ResourceMonitor` host/device-memory summary.
+  cold-dispatch / retry / deadline-miss / degradation accounting and the
+  peak host and device memory, read once when the serve ends.
 
 Fault tolerance (the resilience layer):
 
@@ -82,7 +82,8 @@ from __future__ import annotations
 
 import dataclasses
 import math
-import os
+import resource
+import sys
 import threading
 import time
 from collections import deque
@@ -245,61 +246,25 @@ def burst_trace(n: int = 32, bursts: int = 4, period_s: float = 0.05,
 
 
 # ---------------------------------------------------------------------------
-# resource monitor
+# resources
 # ---------------------------------------------------------------------------
 
 
-def _page_size() -> int:
-    try:
-        return os.sysconf("SC_PAGE_SIZE")
-    except (AttributeError, ValueError, OSError):  # pragma: no cover
-        return 4096
+def _resources() -> Dict[str, float]:
+    """Peak host RSS of the process and, where the backend reports it, the
+    first device's peak bytes in use: read once, when a serve ends."""
+    rss = resource.getrusage(resource.RUSAGE_SELF).ru_maxrss
+    out = {"host_rss_peak_bytes":         # kilobytes, but bytes on macOS
+           float(rss if sys.platform == "darwin" else rss * 1024)}
+    ms = jax.local_devices()[0].memory_stats()   # None on CPU
+    if ms and "peak_bytes_in_use" in ms:
+        out["device_peak_bytes"] = float(ms["peak_bytes_in_use"])
+    return out
 
 
-class ResourceMonitor(threading.Thread):
-    """Daemon thread sampling host RSS (``/proc/self/statm``) and device
-    memory (``Device.memory_stats``, where the backend exposes it) while a
-    serve runs — no psutil dependency, negligible overhead."""
-
-    def __init__(self, interval_s: float = 0.005):
-        super().__init__(daemon=True)
-        self.interval_s = interval_s
-        self._halt = threading.Event()
-        self.host_rss: List[int] = []
-        self.device_bytes: List[int] = []
-
-    def _sample(self) -> None:
-        try:
-            with open("/proc/self/statm") as f:
-                self.host_rss.append(
-                    int(f.read().split()[1]) * _page_size())
-        except (OSError, ValueError, IndexError):  # pragma: no cover
-            pass
-        ms = jax.local_devices()[0].memory_stats()   # None on CPU
-        if ms and "bytes_in_use" in ms:
-            self.device_bytes.append(int(ms["bytes_in_use"]))
-
-    def run(self) -> None:
-        while not self._halt.is_set():
-            self._sample()
-            self._halt.wait(self.interval_s)
-
-    def stop(self) -> Dict[str, float]:
-        """Idempotent stop+join+summarize: safe to call from a
-        ``finally`` even if the monitor already stopped."""
-        self._halt.set()
-        if self.is_alive():
-            self.join(timeout=2.0)
-        self._sample()              # at least one sample, however short
-        out: Dict[str, float] = {
-            "samples": float(len(self.host_rss)),
-            "host_rss_peak_bytes": float(max(self.host_rss, default=0)),
-            "host_rss_mean_bytes": float(np.mean(self.host_rss))
-            if self.host_rss else 0.0,
-        }
-        if self.device_bytes:
-            out["device_peak_bytes"] = float(max(self.device_bytes))
-        return out
+#: a program span: a host annotation in the profiler's trace (a cheap
+#: no-op while no profiler runs)
+_span = jax.profiler.TraceAnnotation
 
 
 # ---------------------------------------------------------------------------
@@ -485,7 +450,6 @@ class _LiveState:
         self.session = _Session(execute=True, closed=False,
                                 faults=engine.faults)
         self.futures: Dict[int, Future] = {}
-        self.monitor = ResourceMonitor()
         self.thread: Optional[threading.Thread] = None
         self.t0 = time.perf_counter()
         self.next_rid = 0
@@ -576,9 +540,11 @@ class ServingEngine:
         return gkey
 
     def _cost_of(self, plan, r: ProxyRequest) -> float:
-        dynb1 = jax.tree_util.tree_map(lambda v: np.asarray(v)[None], r.dyn)
-        c, _ = plan.candidate_costs(dynb1)
-        return float(c[0])
+        with _span("serve.cost", rid=r.rid):
+            dynb1 = jax.tree_util.tree_map(lambda v: np.asarray(v)[None],
+                                           r.dyn)
+            c, _ = plan.candidate_costs(dynb1)
+            return float(c[0])
 
     def _breaker(self, sess: _Session, gkey: Tuple) -> CircuitBreaker:
         br = sess.breakers.get(gkey)
@@ -623,22 +589,26 @@ class ServingEngine:
             return service, cold, []
         m0 = stack.exec_domain().stats["misses"]
         t0 = time.perf_counter()
+        rid = chunk[0].rid
         with forced_backend("xla" if degraded else None):
-            if b == 1:
-                fn = stack._compiled_plan(plan, batch=False)
-                r = chunk[0]
-                # copy the dyn scalars: the batch=False form donates its
-                # dyn buffers on accelerators, and a trace may be replayed
-                dyn = jax.tree_util.tree_map(jnp.array, r.dyn)
-                out, _ = stack._population_call(fn, r.rng, dyn)
-            else:
-                fn = stack._compiled_plan_serve(plan, b)
-                rngs = jnp.stack([r.rng for r in chunk])
-                dynb = jax.tree_util.tree_map(
-                    lambda *xs: jnp.stack([jnp.asarray(x) for x in xs]),
-                    *[r.dyn for r in chunk])
-                out = stack._serve_call(fn, rngs, dynb)
-            jax.block_until_ready(out)
+            with _span("serve.dispatch", rid=rid, n=valid):
+                if b == 1:
+                    fn = stack._compiled_plan(plan, batch=False)
+                    r = chunk[0]
+                    # copy the dyn scalars: the batch=False form donates
+                    # its dyn buffers on accelerators, and a trace may be
+                    # replayed
+                    dyn = jax.tree_util.tree_map(jnp.array, r.dyn)
+                    out = stack._population_call(fn, r.rng, dyn)
+                else:
+                    fn = stack._compiled_plan_serve(plan, b)
+                    rngs = jnp.stack([r.rng for r in chunk])
+                    dynb = jax.tree_util.tree_map(
+                        lambda *xs: jnp.stack([jnp.asarray(x) for x in xs]),
+                        *[r.dyn for r in chunk])
+                    out = stack._serve_call(fn, rngs, dynb)
+            with _span("serve.sync", rid=rid):
+                jax.block_until_ready(out)
         service = time.perf_counter() - t0
         was_cold = stack.exec_domain().stats["misses"] > m0
         host = np.asarray(out)
@@ -826,9 +796,6 @@ class ServingEngine:
             groups[gkey]["remaining"] += 1
             sess.costs[r.rid] = self._cost_of(groups[gkey]["plan"], r)
 
-        monitor = ResourceMonitor()
-        monitor.start()
-
         pending = sorted(requests, key=lambda r: (r.arrival_s, r.rid))
         first_arrival = pending[0].arrival_s if pending else 0.0
         b = 1 if closed else self._chunk_size()
@@ -852,48 +819,48 @@ class ServingEngine:
             head = groups[k]["queue"][0]
             return (head.abs_deadline, head.arrival_s, head.rid)
 
-        try:
-            while i_next < len(pending) or any(g["queue"]
-                                               for g in groups.values()):
-                if closed:
-                    # closed loop: next request becomes ready the instant
-                    # the previous completes — trace arrival is ignored
-                    if not any(g["queue"] for g in groups.values()):
-                        r = pending[i_next]
-                        i_next += 1
-                        g = groups[gkey_of[r.rid]]
-                        g["queue"].append(r)
-                        g["remaining"] -= 1
-                else:
-                    admit(now)
-                nonempty = [k for k, g in groups.items() if g["queue"]]
-                if not nonempty:
-                    now = max(now, pending[i_next].arrival_s)
+        while i_next < len(pending) or any(g["queue"]
+                                           for g in groups.values()):
+            if closed:
+                # closed loop: next request becomes ready the instant
+                # the previous completes — trace arrival is ignored
+                if not any(g["queue"] for g in groups.values()):
+                    r = pending[i_next]
+                    i_next += 1
+                    g = groups[gkey_of[r.rid]]
+                    g["queue"].append(r)
+                    g["remaining"] -= 1
+            else:
+                admit(now)
+            nonempty = [k for k, g in groups.items() if g["queue"]]
+            if not nonempty:
+                now = max(now, pending[i_next].arrival_s)
+                continue
+            if wait > 0.0:
+                # partial-chunk flush policy: a lane is dispatchable
+                # when a full chunk waits, no future arrival can ever
+                # fill it, or its head has waited out the flush
+                # timeout — the P99 hostage bound
+                def ready(k: Tuple) -> bool:
+                    g = groups[k]
+                    return (len(g["queue"]) >= b
+                            or g["remaining"] == 0
+                            or now - g["queue"][0].arrival_s
+                            >= wait - 1e-12)
+                ready_keys = [k for k in nonempty if ready(k)]
+                if not ready_keys:
+                    flush_at = min(
+                        groups[k]["queue"][0].arrival_s + wait
+                        for k in nonempty)
+                    next_arr = (pending[i_next].arrival_s
+                                if i_next < len(pending) else math.inf)
+                    now = min(flush_at, next_arr)
                     continue
-                if wait > 0.0:
-                    # partial-chunk flush policy: a lane is dispatchable
-                    # when a full chunk waits, no future arrival can ever
-                    # fill it, or its head has waited out the flush
-                    # timeout — the P99 hostage bound
-                    def ready(k: Tuple) -> bool:
-                        g = groups[k]
-                        return (len(g["queue"]) >= b
-                                or g["remaining"] == 0
-                                or now - g["queue"][0].arrival_s
-                                >= wait - 1e-12)
-                    ready_keys = [k for k in nonempty if ready(k)]
-                    if not ready_keys:
-                        flush_at = min(
-                            groups[k]["queue"][0].arrival_s + wait
-                            for k in nonempty)
-                        next_arr = (pending[i_next].arrival_s
-                                    if i_next < len(pending) else math.inf)
-                        now = min(flush_at, next_arr)
-                        continue
-                else:
-                    ready_keys = nonempty
-                # drain the most urgent lane: earliest absolute deadline
-                # first, oldest waiting head otherwise
+            else:
+                ready_keys = nonempty
+            # drain the most urgent lane: earliest absolute deadline
+            # first, oldest waiting head otherwise
+            with _span("serve.group") as span:
                 gkey = min(ready_keys, key=urgency)
                 g = groups[gkey]
                 if (wait > 0.0 and len(g["queue"]) < b
@@ -902,10 +869,9 @@ class ServingEngine:
                     sess.timeout_flushes += 1
                 k = min(max_batch, len(g["queue"]))
                 batch = [g["queue"].popleft() for _ in range(k)]
-                now += self._serve_batch(sess, g, gkey, batch, b, now)
-        finally:
-            # never leak the sampler thread, even on an exception
-            resources = monitor.stop()
+                span.set_metadata(rid=batch[0].rid, n=k)
+            now += self._serve_batch(sess, g, gkey, batch, b, now)
+        resources = _resources()
         return self._build_report(sess, requests, len(groups),
                                   first_arrival, now, clock, mode,
                                   resources)
@@ -970,7 +936,6 @@ class ServingEngine:
             raise RuntimeError("ServingEngine is already started")
         live = _LiveState(self)
         self._live = live
-        live.monitor.start()
         live.thread = threading.Thread(target=self._live_loop, daemon=True)
         live.thread.start()
         return self
@@ -1054,19 +1019,19 @@ class ServingEngine:
                                     >= wait - 1e-12)
                         ready_keys = [k for k in nonempty if ready(k)]
                         if ready_keys:
-                            gkey = min(
-                                ready_keys,
-                                key=lambda k: (
+                            with _span("serve.group") as span:
+                                gkey = min(ready_keys, key=lambda k: (
                                     live.groups[k]["queue"][0].abs_deadline,
                                     live.groups[k]["queue"][0].arrival_s,
                                     live.groups[k]["queue"][0].rid))
-                            g = live.groups[gkey]
-                            if (wait > 0.0 and len(g["queue"]) < b
-                                    and not live.stopping):
-                                sess.timeout_flushes += 1
-                            k = min(self.max_batch, len(g["queue"]))
-                            batch = [g["queue"].popleft()
-                                     for _ in range(k)]
+                                g = live.groups[gkey]
+                                if (wait > 0.0 and len(g["queue"]) < b
+                                        and not live.stopping):
+                                    sess.timeout_flushes += 1
+                                k = min(self.max_batch, len(g["queue"]))
+                                batch = [g["queue"].popleft()
+                                         for _ in range(k)]
+                                span.set_metadata(rid=batch[0].rid, n=k)
                             break
                         flush_in = min(
                             live.groups[k]["queue"][0].arrival_s + wait
@@ -1116,8 +1081,7 @@ class ServingEngine:
         """Stop the dispatcher and return the live session's
         :class:`ServeReport`.  ``drain=True`` (default) serves everything
         already submitted first; ``drain=False`` fails pending requests'
-        futures immediately.  The resource monitor is always joined —
-        shutdown never leaks the sampler thread."""
+        futures immediately."""
         live = self._live
         if live is None:
             raise RuntimeError("ServingEngine.shutdown without start()")
@@ -1142,8 +1106,8 @@ class ServingEngine:
             if live.thread is not None:
                 live.thread.join(timeout=10.0)
         finally:
-            resources = live.monitor.stop()
             self._live = None
+        resources = _resources()
         sess = live.session
         requests: List[ProxyRequest] = []
         # statuses/latencies index by rid; rebuild the admitted order
