@@ -813,10 +813,11 @@ class SparkStack(_MeshStack):
             # place each bucket over the workers: every worker evaluates
             # its partition of the bucket's candidate slice (no sync —
             # the assembly's host transfer forces completion)
-            dynb = jax.device_put(
-                dynb, bucket_shardings(self.mesh, dynb,
-                                       prefer=(self.axis,)))
-            rng = jax.device_put(rng, NamedSharding(self.mesh, P()))
+            with _span("spark.place"):
+                dynb = jax.device_put(
+                    dynb, bucket_shardings(self.mesh, dynb,
+                                           prefer=(self.axis,)))
+                rng = jax.device_put(rng, NamedSharding(self.mesh, P()))
             return fn(rng, dynb)
 
     def _serve_call(self, fn, rngs, dynb):
@@ -824,11 +825,12 @@ class SparkStack(_MeshStack):
         with self.mesh:
             # place the micro-batch over the workers: the paired request
             # axes of rngs and dyn params partition together
-            rng_s, dyn_s = serve_shardings(self.mesh, rngs, dynb,
-                                           prefer=(self.axis,))
-            out = fn(jax.device_put(rngs, rng_s),
-                     jax.device_put(dynb, dyn_s))
-        return out
+            with _span("spark.place"):
+                rng_s, dyn_s = serve_shardings(self.mesh, rngs, dynb,
+                                               prefer=(self.axis,))
+                rngs = jax.device_put(rngs, rng_s)
+                dynb = jax.device_put(dynb, dyn_s)
+            return fn(rngs, dynb)
 
 
 class HadoopStack(Stack):

@@ -5,11 +5,13 @@ checker / snippet extractor in ``tools/check_docs.py`` find zero rot."""
 import sys
 from pathlib import Path
 
+import pytest
+
 ROOT = Path(__file__).resolve().parent.parent
 sys.path.insert(0, str(ROOT))
 
-from tools.check_docs import (DOC_FILES, check_references, documented_api,
-                              extract_snippets)
+from tools.check_docs import (DOC_FILES, _dangling_path, check_references,
+                              documented_api, extract_snippets)
 
 
 def test_api_all_matches_documented_surface():
@@ -39,6 +41,21 @@ def test_docs_have_no_dangling_references():
         assert path.exists(), f"{rel} missing"
         problems += check_references(path, do_import=True)
     assert not problems, "\n".join(problems)
+
+
+@pytest.mark.parametrize("tok,dangling", [
+    ("tools/check_docs.py", False),
+    ("docs/", False),
+    ("tools/check_docs.py:1", False),
+    ("tools/check_docs.py:1-2", False),
+    ("tools/check_docs.py:100000", True),
+    ("tools/check_docs.py:1-100000", True),
+    ("tests/test_docs.py::test_docs_have_no_dangling_references", False),
+    ("tests/test_docs.py::no_such_test", True),
+    ("tools/no_such_file.py:1", True),
+])
+def test_references_to_a_place_in_a_file_are_checked(tok, dangling):
+    assert _dangling_path(tok) is dangling
 
 
 def test_docs_snippets_exist_and_compile():

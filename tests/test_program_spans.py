@@ -4,6 +4,7 @@ order inside their stage, ``RunReport.h2d_bytes`` / ``d2h_bytes`` equal
 the bytes counted by hand, and a live serving session records its
 spans."""
 
+import copy
 import pathlib
 
 import jax
@@ -12,10 +13,11 @@ import numpy as np
 import pytest
 from jax.profiler import ProfileData
 
-from repro.api import ParamSpace, get_stack
+from repro.api import ParamSpace, ProxySpec, get_stack
 from repro.core import engine
 from repro.core.dag import Edge, ProxyDAG
 from repro.core.dwarfs import ComponentParams
+from repro.core.workloads import PAGERANK_PROXY_SPEC
 from repro.serve.engine import ProxyRequest, ServingEngine
 
 #: elements per buffer: a size no other test compiles, so the engine
@@ -127,6 +129,33 @@ def test_openmp_population_records_every_span(tmp_path, bucket_size):
     buckets = sorted(ev[3]["bucket"] for evs in lines.values()
                      for ev in evs if ev[0] == "stack.dispatch")
     assert buckets == list(range(POP // bucket_size))
+
+
+def _pagerank_dag(n: int) -> ProxyDAG:
+    spec = copy.deepcopy(PAGERANK_PROXY_SPEC)
+    spec["sources"] = {k: n for k in spec["sources"]}
+    for e in spec["edges"]:
+        e["data_size"] = n
+    return ProxySpec.from_json(spec).to_benchmark().dag
+
+
+@pytest.mark.parametrize("bucket_size", [1, 2])
+def test_spark_population_places_each_bucket_inside_its_dispatch(
+        tmp_path, bucket_size):
+    dag = _pagerank_dag(5 * 256)
+    space, matrix = _candidates(dag)
+    rep, lines = _traced(tmp_path, lambda: get_stack("spark").run_population(
+        dag, matrix, space=space, bucket_size=bucket_size))
+    assert np.asarray(rep.result).shape == (POP,)
+    places = 0
+    for evs in lines.values():
+        for name, s, e, _ in evs:
+            if name != "spark.place":
+                continue
+            places += 1
+            assert any(d[0] == "stack.dispatch" and d[1] <= s and e <= d[2]
+                       for d in evs)
+    assert places == POP // bucket_size
 
 
 def test_hadoop_population_bytes_match_the_count_by_hand(unfused):

@@ -74,6 +74,27 @@ def _path_candidates(tok: str) -> List[Path]:
     return out
 
 
+#: a place in a file: ``path:line``, ``path:first-last`` or ``path::name``
+_LOCATION = re.compile(r"(?P<path>[^:]+)(?::(?P<first>\d+)(?:-(?P<last>\d+))?"
+                       r"|::(?P<name>\w+))")
+
+
+def _dangling_path(tok: str) -> bool:
+    """Whether ``tok`` names no file here, or a line past the file's end,
+    or a ``def``/``class`` the file does not hold."""
+    loc = _LOCATION.fullmatch(tok)
+    if not loc:
+        return not any(c.exists() for c in _path_candidates(tok))
+    files = [c for c in _path_candidates(loc["path"]) if c.is_file()]
+    if not files:
+        return True
+    text = files[0].read_text()
+    if loc["name"]:
+        return not re.search(rf"^\s*(?:def|class) {loc['name']}\b", text,
+                             re.M)
+    return int(loc["last"] or loc["first"]) > len(text.splitlines())
+
+
 def _is_dotted_name(tok: str) -> bool:
     tok = tok.rstrip("()")
     if not re.fullmatch(r"[A-Za-z_][\w.]*", tok) or "." not in tok:
@@ -127,7 +148,7 @@ def check_references(path: Path, do_import: bool = False) -> List[str]:
         if any(tok.startswith(p) for p in KNOWN_EXTERNAL):
             continue
         if _is_path_token(tok):
-            if not any(c.exists() for c in _path_candidates(tok)):
+            if _dangling_path(tok):
                 problems.append(f"{path.name}: dangling path `{tok}`")
         elif _is_dotted_name(tok):
             why = _resolve_dotted(tok, do_import)
