@@ -48,7 +48,9 @@ from .stack import (FAILURE_CLASSES, HadoopStack, MPIStack, OpenMPStack,
                     RunReport, SparkStack, Stack, cache_cap, cache_stats,
                     classify_failure, failure_is_retryable, get_stack,
                     list_stacks, register_stack, reset_cache_stats)
-from ..core.dwarfs.statistic import grouped_stats, reset_grouped_stats
+from ..core.dwarfs.statistic import (grouped_stats, reset_grouped_stats,
+                                     reset_segment_max_stats,
+                                     segment_max_stats)
 from ..core.engine import (FINGERPRINT_CHANNELS, FINGERPRINT_VERSION,
                            WorkloadFingerprint, fingerprint)
 from ..core.pool import ExecutablePool, get_pool, pool_stats
@@ -107,6 +109,7 @@ __all__ = [
     "Stack", "cache_cap", "cache_stats", "get_stack", "list_stacks",
     "register_stack", "reset_cache_stats", "tune_structure",
     "grouped_stats", "reset_grouped_stats",
+    "segment_max_stats", "reset_segment_max_stats",
     "ExecutablePool", "get_pool", "pool_stats", "serve",
     "FAILURE_CLASSES", "classify_failure", "failure_is_retryable",
     "FaultPlan", "InjectedFailure", "default_fault_rate",

@@ -1,7 +1,9 @@
 """Graph computation dwarf — construction, traversal, degree counting.
 
 Irregular gather/scatter-dominant access patterns (the paper singles graph
-computations out for exactly this).
+computations out for exactly this).  The order-free scatters onto a small
+vertex table (degree counts, the hop's max) go through the dense blocked
+reductions of :mod:`.statistic`, which give the scatter's bits.
 """
 
 from __future__ import annotations
@@ -10,6 +12,7 @@ import jax
 import jax.numpy as jnp
 
 from .base import ComponentParams, DwarfComponent, as_u32, register
+from .statistic import grouped_counts, segment_max
 
 
 def _edges_from_buffer(x: jnp.ndarray, n_vertices: int):
@@ -30,8 +33,8 @@ class GraphConstruction(DwarfComponent):
     def apply(self, x: jnp.ndarray, p: ComponentParams, rng: jax.Array):
         v = int(p.extra.get("vertices", max(64, x.shape[0] // 8)))
         src, dst = _edges_from_buffer(x, v)
-        out_deg = jnp.zeros((v,), jnp.float32).at[src].add(1.0)
-        in_deg = jnp.zeros((v,), jnp.float32).at[dst].add(1.0)
+        out_deg = grouped_counts(src, v)
+        in_deg = grouped_counts(dst, v)
         gathered = out_deg[src] + in_deg[dst]       # gather back along edges
         return gathered
 
@@ -50,7 +53,7 @@ class GraphTraversal(DwarfComponent):
         frontier = jnp.zeros((v,), jnp.float32).at[0].set(1.0)
 
         def hop(f, _):
-            nxt = jnp.zeros((v,), jnp.float32).at[dst].max(f[src])
+            nxt = segment_max(f[src], dst, v)
             return jnp.maximum(f, nxt), ()
 
         frontier, _ = jax.lax.scan(hop, frontier, None, length=hops)
@@ -67,8 +70,9 @@ class SpMV(DwarfComponent):
     def apply(self, x: jnp.ndarray, p: ComponentParams, rng: jax.Array):
         v = int(p.extra.get("vertices", max(64, x.shape[0] // 8)))
         src, dst = _edges_from_buffer(x, v)
-        deg = jnp.zeros((v,), jnp.float32).at[src].add(1.0)
+        deg = grouped_counts(src, v)
         rank = jnp.full((v,), 1.0 / v)
         contrib = rank[src] / jnp.maximum(deg[src], 1.0)
+        # the sum keeps the scatter: a float sum depends on its order
         new_rank = jnp.zeros((v,), jnp.float32).at[dst].add(contrib)
         return new_rank[dst]

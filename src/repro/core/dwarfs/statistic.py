@@ -11,17 +11,23 @@ from .base import (ComponentParams, DwarfComponent, as_chunks, as_u32,
                    mix_u32, register)
 
 
-#: the largest static group count whose counts take the dense reduction;
-#: above it the scatter, whose cost does not grow with the group count, is
-#: cheaper.  Set from a sweep of one ``grouped_count`` repeat at 2**22 on a
-#: TPU v5e (PERF.md §5).
+#: the largest static group count whose counts (and segment maxima) take
+#: the dense reduction; above it the scatter, whose cost does not grow
+#: with the group count, is cheaper.  Set from sweeps of one
+#: ``grouped_count`` repeat at 2**22 and of the counts and the maximum
+#: at 2**21 ids on a TPU v5e (PERF.md §5, §6): the counts cross over
+#: between 4096 and 8192 groups, the maximum above 8192.
 DENSE_GROUPS_MAX = 4096
 
 #: lowering-time counters: how many grouped counts were traced through the
 #: dense reduction and how many through the scatter
 GROUPED_STATS = {"dense": 0, "scatter": 0}
 
-#: group-by-element compares per loop step of the dense counts: bounds
+#: lowering-time counters of :func:`segment_max`, kept apart from
+#: :data:`GROUPED_STATS`
+SEGMENT_MAX_STATS = {"dense": 0, "scatter": 0}
+
+#: group-by-element compares per loop step of a dense reduction: bounds
 #: the step's ``[groups, block]`` temporary where it is materialised (the
 #: CPU) and keeps the loop short where it is fused (a TPU: 8 steps for
 #: 16 groups at 2**22)
@@ -40,6 +46,37 @@ def reset_grouped_stats() -> None:
         GROUPED_STATS[k] = 0
 
 
+def segment_max_stats() -> Dict[str, int]:
+    """How many segment maxima were traced through each path, ``dense``
+    and ``scatter`` (counted per trace, not per call)."""
+    return dict(SEGMENT_MAX_STATS)
+
+
+def reset_segment_max_stats() -> None:
+    """Zero the segment-max path counters."""
+    for k in SEGMENT_MAX_STATS:
+        SEGMENT_MAX_STATS[k] = 0
+
+
+def _dense_reduce(gid, groups, init, step, *values):
+    """Fold ``step(acc, ids, *value_blocks)`` over the flat group ids
+    ``gid`` a block of ``ids`` at a time, from ``init``: ``step``
+    compares the block with every group.  Padded ids match no group, so
+    padded values (0) reach no group."""
+    n = gid.shape[0]
+    block = min(n, _STEP_COMPARES // groups)
+    steps = -(-n // block)
+
+    def blocks(a, fill):
+        return jnp.pad(a, (0, steps * block - n),
+                       constant_values=fill).reshape(steps, block)
+
+    out, _ = jax.lax.scan(lambda acc, blk: (step(acc, *blk), None), init,
+                          (blocks(gid, groups),
+                           *(blocks(v, 0) for v in values)))
+    return out
+
+
 def grouped_counts(gid: jnp.ndarray, groups: int) -> jnp.ndarray:
     """How many of the flat group ids ``gid`` fall in each group, float32
     ``[groups]``.
@@ -56,19 +93,37 @@ def grouped_counts(gid: jnp.ndarray, groups: int) -> jnp.ndarray:
         return jax.ops.segment_sum(jnp.ones(gid.shape, jnp.float32), gid,
                                    num_segments=groups)
     GROUPED_STATS["dense"] += 1
-    n = gid.shape[0]
-    block = min(n, _STEP_COMPARES // groups)
-    steps = -(-n // block)
-    # padded ids match no group
-    gb = jnp.pad(gid, (0, steps * block - n),
-                 constant_values=groups).reshape(steps, block)
     ids = jnp.arange(groups, dtype=gid.dtype)[:, None]
-
-    def count(acc, g):
-        return acc + jnp.sum(g[None, :] == ids, axis=1, dtype=jnp.int32), None
-
-    counts, _ = jax.lax.scan(count, jnp.zeros((groups,), jnp.int32), gb)
+    counts = _dense_reduce(
+        gid, groups, jnp.zeros((groups,), jnp.int32),
+        lambda acc, g: acc + jnp.sum(g[None, :] == ids, axis=1,
+                                     dtype=jnp.int32))
     return counts.astype(jnp.float32)
+
+
+def segment_max(values: jnp.ndarray, gid: jnp.ndarray,
+                groups: int) -> jnp.ndarray:
+    """The largest of ``values`` in each of ``groups`` segments and 0,
+    ``[groups]``: ``jnp.zeros((groups,)).at[gid].max(values)``.
+
+    Up to :data:`DENSE_GROUPS_MAX` groups each block of ids is compared
+    with every group, the value or 0 selected, and the maximum taken
+    over the block.  A maximum rounds nothing, so the result is the
+    scatter's in any order (for values free of NaN and of -0.0).
+    """
+    if groups > DENSE_GROUPS_MAX:
+        SEGMENT_MAX_STATS["scatter"] += 1
+        return jnp.zeros((groups,), values.dtype).at[gid].max(values)
+    SEGMENT_MAX_STATS["dense"] += 1
+    # the block is compared as a column with the groups as a row, and
+    # the maximum taken down the columns: XLA's CPU backend takes a float
+    # maximum along rows some five times slower
+    ids = jnp.arange(groups, dtype=gid.dtype)[None, :]
+    return _dense_reduce(
+        gid, groups, jnp.zeros((groups,), values.dtype),
+        lambda acc, g, v: jnp.maximum(acc, jnp.max(
+            jnp.where(g[:, None] == ids, v[:, None], 0), axis=0)),
+        values)
 
 
 @register

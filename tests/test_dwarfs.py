@@ -7,8 +7,12 @@ import pytest
 
 from repro.core.dwarfs import DWARFS, REGISTRY, ComponentParams, get_component
 from repro.core.dwarfs.base import as_u32, fit_buffer, mix_u32
+from repro.core.dwarfs import statistic
+from repro.core.dwarfs.graph import _edges_from_buffer
 from repro.core.dwarfs.statistic import (DENSE_GROUPS_MAX, grouped_counts,
-                                         grouped_stats, reset_grouped_stats)
+                                         grouped_stats, reset_grouped_stats,
+                                         reset_segment_max_stats, segment_max,
+                                         segment_max_stats)
 
 P = ComponentParams(data_size=2048, chunk_size=128, parallelism=1, weight=1)
 
@@ -188,6 +192,120 @@ def test_grouped_count_temporaries_stay_linear(groups):
     compiled = jax.jit(_body("grouped_count", groups)).lower(
         jax.ShapeDtypeStruct((n,), jnp.float32)).compile()
     assert compiled.memory_analysis().temp_size_in_bytes < 4 * n * 4
+
+
+# ---------------------------------------------------------------------------
+# graph dwarfs: dense degree counts and hop max against the scatters
+# ---------------------------------------------------------------------------
+
+def _graph_reduction(reduction, vertices, dense):
+    """A degree count or a hop max over the edges a buffer holds, through
+    :mod:`statistic`'s router (``dense``) or the scatter onto zeros."""
+    def f(x):
+        src, dst = _edges_from_buffer(x, vertices)
+        zeros = jnp.zeros((vertices,), jnp.float32)
+        if reduction == "counts":
+            return (grouped_counts(dst, vertices) if dense
+                    else zeros.at[dst].add(1.0))
+        vals = x[:src.shape[0]]                 # signed, so 0 wins some
+        return (segment_max(vals, dst, vertices) if dense
+                else zeros.at[dst].max(vals))
+    return f
+
+
+@pytest.mark.parametrize("block", [None, 300])
+@pytest.mark.parametrize("reduction", ["counts", "max"])
+@pytest.mark.parametrize("vertices", [64, 512, 4096, DENSE_GROUPS_MAX + 1])
+def test_graph_reduction_matches_scatter(reduction, vertices, block, rng,
+                                         monkeypatch):
+    """The dense degree counts and hop max give the scatter's bits, lane
+    alone and inside a vmap of 4; in one block, or in blocks of 300 ids
+    whose last is cut short."""
+    if block:
+        monkeypatch.setattr(statistic, "_STEP_COMPARES", vertices * block)
+    xs = jax.random.normal(rng, (4, GROUPED_N))
+    dense = jax.jit(_graph_reduction(reduction, vertices, True))
+    scatter = jax.jit(_graph_reduction(reduction, vertices, False))
+    lanes = np.asarray(jax.jit(jax.vmap(
+        _graph_reduction(reduction, vertices, True)))(xs))
+    for i in range(xs.shape[0]):
+        alone = np.asarray(dense(xs[i]))
+        np.testing.assert_array_equal(alone, lanes[i])
+        np.testing.assert_array_equal(alone, np.asarray(scatter(xs[i])))
+
+
+def _scatter_form(name, x, p):
+    """The graph dwarfs as written with scatters, before the dense
+    reductions."""
+    v = p.extra["vertices"]
+    src, dst = _edges_from_buffer(x, v)
+    zeros = jnp.zeros((v,), jnp.float32)
+    if name == "graph_construction":
+        return zeros.at[src].add(1.0)[src] + zeros.at[dst].add(1.0)[dst]
+    if name == "graph_traversal":
+        def hop(f, _):
+            return jnp.maximum(f, zeros.at[dst].max(f[src])), ()
+        frontier, _ = jax.lax.scan(hop, zeros.at[0].set(1.0), None,
+                                   length=p.extra["hops"])
+        return frontier[dst % v]
+    deg = zeros.at[src].add(1.0)
+    contrib = jnp.full((v,), 1.0 / v)[src] / jnp.maximum(deg[src], 1.0)
+    return zeros.at[dst].add(contrib)[dst]
+
+
+@pytest.mark.parametrize("name", ["graph_construction", "graph_traversal",
+                                  "spmv"])
+@pytest.mark.parametrize("vertices", [64, 512, 4096])
+def test_graph_dwarf_equals_scatter_form(name, vertices, rng):
+    """Each graph dwarf gives its scatter form's bits, lane alone and
+    inside a vmap of 4."""
+    comp = get_component(name)
+    p = P.replace(data_size=GROUPED_N, extra={"vertices": vertices,
+                                              "hops": 4})
+    xs = jax.random.normal(rng, (4, GROUPED_N))
+    run = jax.jit(lambda v: comp.apply(v, p, rng))
+    lanes = np.asarray(jax.jit(jax.vmap(
+        lambda v: comp.apply(v, p, rng)))(xs))
+    before = jax.jit(lambda v: _scatter_form(name, v, p))
+    for i in range(xs.shape[0]):
+        alone = np.asarray(run(xs[i]))
+        np.testing.assert_array_equal(alone, lanes[i])
+        np.testing.assert_array_equal(alone, np.asarray(before(xs[i])))
+
+
+#: degree counts and hop maxima each graph dwarf traces
+_GRAPH_REDUCTIONS = {"graph_construction": (2, 0), "graph_traversal": (0, 1),
+                     "spmv": (1, 0)}
+
+
+@pytest.mark.parametrize("name,vertices,path,scatters", [
+    ("graph_construction", 512, "dense", 0),
+    ("graph_construction", DENSE_GROUPS_MAX + 1, "scatter", 2),
+    ("graph_traversal", 512, "dense", 0),
+    ("graph_traversal", DENSE_GROUPS_MAX + 1, "scatter", 1),
+    ("spmv", 512, "dense", 1),                  # the rank sum's scatter
+    ("spmv", DENSE_GROUPS_MAX + 1, "scatter", 2)])
+def test_graph_lowering_path(name, vertices, path, scatters):
+    """Up to the threshold no degree count or hop max lowers to a
+    scatter, above it each keeps its scatter; the counters count the
+    path each was traced through."""
+    reset_grouped_stats()
+    reset_segment_max_stats()
+    comp = get_component(name)
+
+    def body(v):
+        p = ComponentParams(data_size=v.shape[0], chunk_size=64,
+                            extra={"vertices": vertices, "hops": 2})
+        return comp.apply(v, p, jax.random.PRNGKey(0))
+    text = jax.jit(body).lower(
+        jax.ShapeDtypeStruct((GROUPED_N,), jnp.float32)).compile().as_text()
+    assert text.count(" scatter(") == scatters
+    counts, maxima = _GRAPH_REDUCTIONS[name]
+    dense = path == "dense"
+    assert grouped_stats() == {"dense": counts * dense,
+                               "scatter": counts * (not dense)}
+    assert segment_max_stats() == {"dense": maxima * dense,
+                                   "scatter": maxima * (not dense)}
 
 
 def test_histogram_counts_match_bincount(rng):

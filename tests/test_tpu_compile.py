@@ -122,3 +122,19 @@ def test_megakernel_stage_compiles_for_v5e(size, one_chip, monkeypatch):
     text = _plan_text(dag, plan, one_chip)
     assert schedule.mega_stats()["mega"] == 1
     assert text.count("tpu_custom_call") >= 1
+
+
+@pytest.mark.parametrize("name", ["graph_construction", "graph_traversal"])
+def test_graph_reductions_compile_dense_for_v5e(name, one_chip):
+    """At the TeraSort cell's size (2**21 edges onto 512 vertices) the
+    degree counts and the hop max lower to no scatter, and the blocked
+    ``[vertices, block]`` compare is fused: the temporaries stay under
+    four buffers."""
+    n = 1 << 22
+    comp = get_component(name)
+    p = Edge.from_json(PROXY_SPECS["terasort"]["edges"][0]).params.replace(
+        data_size=n, extra={"vertices": 512, "hops": 2})
+    compiled = jax.jit(lambda x, r: comp(x, p, r)).lower(
+        _spec((n,), jnp.float32, one_chip), _rng_spec(one_chip)).compile()
+    assert " scatter(" not in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 * n * 4
